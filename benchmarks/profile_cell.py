@@ -18,7 +18,7 @@ mesh = make_production_mesh(multi_pod=False)
 cell = build_cell(arch, shape, multi_pod=False)
 dn = donate.get(cell.shape.kind, (1,))
 t0 = time.perf_counter()
-with mesh:
+with jax.set_mesh(mesh):
     compiled = jax.jit(cell.fn, in_shardings=shard(mesh, cell.in_specs),
                        out_shardings=shard(mesh, cell.out_specs),
                        donate_argnums=dn).lower(*cell.abstract_args).compile()

@@ -15,8 +15,9 @@ import jax.numpy as jnp         # noqa: E402
 import numpy as np              # noqa: E402
 
 from repro.dist import gpipe    # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = make_mesh((8,), ("pod",))
 P_STAGES, D, B, M = 8, 64, 32, 4
 
 key = jax.random.PRNGKey(0)

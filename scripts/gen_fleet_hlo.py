@@ -45,7 +45,7 @@ def generate(arch: str) -> pathlib.Path:
                               tp=2)
     cell = build_cell(arch, SHAPE, cfg=cfg)
     mesh = make_local_mesh(data=2, model=2)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(
             cell.fn,
             in_shardings=shard(mesh, cell.in_specs),

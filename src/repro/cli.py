@@ -275,7 +275,9 @@ def build_parser() -> argparse.ArgumentParser:
     meas.add_argument("--measure", dest="measure", action="store_true",
                       default=True,
                       help="measure the shortlist with real timers "
-                           "(default)")
+                           "(default): compiled kernels on a TPU, the "
+                           "Pallas interpreter elsewhere; exits 1 when "
+                           "the default candidate fails to measure")
     meas.add_argument("--no-measure", dest="measure", action="store_false",
                       help="stop after the analytic ranking")
     sp.add_argument("--warmup", type=int, default=1,
@@ -290,7 +292,7 @@ def build_parser() -> argparse.ArgumentParser:
                     default=True,
                     help="time in-process instead of per-candidate "
                          "subprocesses (faster, no crash/timeout "
-                         "protection)")
+                         "protection); a TPU is always timed in-process")
     sp.add_argument("--apply-calibration", nargs="?", const="auto",
                     default=None, metavar="YAML",
                     help="write the derived calibration factors into the "
@@ -752,11 +754,18 @@ def cmd_tune(args) -> int:
         except ValueError:
             config[name] = value        # dtype=..., causal=... stay strings
     service = _service(args)
-    rep = tune_mod.tune(args.family, machine, config=config or None,
-                        top_k=args.top_k, measure=args.measure,
-                        warmup=args.warmup, reps=args.reps,
-                        timeout_s=args.timeout_s, isolate=args.isolate,
-                        service=service)
+    if args.measure:                # timing compiles through JAX
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
+    try:
+        rep = tune_mod.tune(args.family, machine, config=config or None,
+                            top_k=args.top_k, measure=args.measure,
+                            warmup=args.warmup, reps=args.reps,
+                            timeout_s=args.timeout_s, isolate=args.isolate,
+                            service=service)
+    except tune_mod.TuneFailed as e:
+        print(f"error: {e}", file=sys.stderr)
+        return 1
     applied = None
     if args.apply_calibration is not None:
         if not rep.calibration:

@@ -397,6 +397,28 @@ def _round_down(v: int, granule: int) -> int:
     return max(granule, (v // granule) * granule)
 
 
+def padded_plane_bytes(n0: int, n1: int, elem_bytes: int) -> int:
+    """Bytes one ``(n0, n1)`` plane takes in VMEM: padded to whole
+    ``(SUBLANE, LANE)`` tiles."""
+    return (-(-n0 // SUBLANE) * SUBLANE) * (-(-n1 // LANE) * LANE) \
+        * elem_bytes
+
+
+#: VMEM slack for the compiler's own small allocations in a plane kernel
+PLANE_VMEM_HEADROOM = 1 << 20
+
+
+def plane_pipeline_vmem(n_inputs: int, n_temps: int, n0: int, n1: int,
+                        elem_bytes: int) -> int:
+    """VMEM held by a Pallas kernel that streams whole ``(n0, n1)`` planes:
+    the pipeline double-buffers each of its ``n_inputs`` input planes and
+    its output plane, and the kernel body keeps ``n_temps`` more planes of
+    temporaries live, plus :data:`PLANE_VMEM_HEADROOM`."""
+    planes = 2 * (n_inputs + 1) + n_temps
+    return planes * padded_plane_bytes(n0, n1, elem_bytes) \
+        + PLANE_VMEM_HEADROOM
+
+
 @dataclasses.dataclass(frozen=True)
 class StencilBlock:
     bk: int

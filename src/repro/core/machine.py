@@ -471,3 +471,19 @@ def load(name: str) -> Machine:
         "V5E": "tpu_v5e.yaml",
     }
     return Machine.from_yaml(aliases.get(name.upper(), name))
+
+
+#: bundled machine file for each accelerator, keyed by the
+#: ``device_kind`` JAX reports for it
+MACHINE_FOR_DEVICE_KIND = {"TPU v5 lite": "tpu_v5e.yaml"}
+
+
+def for_device_kind(kind: str) -> Machine:
+    """The machine description of a JAX device kind; an unknown kind is an
+    error, never a default."""
+    try:
+        return load(MACHINE_FOR_DEVICE_KIND[kind])
+    except KeyError:
+        raise ValueError(
+            f"no machine file for device kind {kind!r}; known: "
+            f"{sorted(MACHINE_FOR_DEVICE_KIND)}") from None

@@ -21,7 +21,7 @@ try:
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     HAVE_JAX = True
 except ImportError:                   # pragma: no cover
     jax = jnp = P = shard_map = None  # type: ignore[assignment]
@@ -84,7 +84,7 @@ def gpipe(stage, mesh, axis: str = "pod", n_microbatches: int = 4):
 
         @functools.partial(
             shard_map, mesh=mesh, in_specs=(P(axis), P(None)),
-            out_specs=P(None), check_rep=False)
+            out_specs=P(None), check_vma=False)
         def run(w_local, mbs):
             p = jax.lax.axis_index(axis)
             w = w_local[0]

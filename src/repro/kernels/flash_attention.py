@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
 NEG_INF = -2.0 ** 30
 
 #: Starting configurations for the autotuner (:mod:`repro.tune`), keyed by
@@ -132,10 +134,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     "causal", "block_q", "block_kv", "interpret", "q_offset"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                     block_kv: int = 128, q_offset: int | None = None,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q: (b, h, sq, d); k, v: (b, h, skv, d). Grouped-head (GQA) callers
     broadcast/reshape kv before the call. ``q_offset`` is the absolute
-    position of q[0] in the kv sequence (decode: skv - sq)."""
+    position of q[0] in the kv sequence (decode: skv - sq).
+    ``interpret=None`` compiles on a TPU and interprets elsewhere."""
     b, h, sq, d = q.shape
     skv = k.shape[2]
     if q_offset is None:
@@ -164,6 +167,6 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
             pltpu.VMEM((block_q,), jnp.float32),       # l
             pltpu.VMEM((block_q, d), jnp.float32),     # acc
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d)

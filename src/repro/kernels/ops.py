@@ -1,11 +1,11 @@
-"""Public jit'd wrappers for the Pallas kernels, with block sizes chosen by
-the layer-condition blocking advisor (core.blocking) against the target
+"""Public wrappers for the Pallas kernels, with block sizes chosen by the
+layer-condition blocking advisor (core.blocking) against the target
 machine's VMEM — the paper's §2.4.2 "ab-initio blocking factors" applied to
-software-managed memory. On CPU (this container) kernels run in
-interpret=True mode; on a real TPU backend, pass interpret=False."""
+software-managed memory. Kernels compile on a TPU backend and run in the
+Pallas interpreter on any other (``interpret=None``, see
+:mod:`repro.kernels.backend`); an explicit bool overrides that."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import blocking, machine as machine_mod
@@ -23,45 +23,45 @@ def _v5e():
     return _V5E
 
 
-def stencil3d7pt(a, coeffs, interpret: bool = True):
-    """Validates the 3-plane working set (3D layer condition) fits VMEM."""
-    M, N, _ = a.shape
+def _check_vmem(name: str, need: int, radius: int, shape, n_arrays: int,
+                elem_bytes: int) -> None:
+    """Refuse a plane size whose pipelined working set exceeds the chip's
+    physical VMEM, before the compiler sees it."""
     vmem = _v5e().vmem_bytes
-    blk = blocking.stencil_blocks(1, (M, N, N), n_arrays=2,
-                                  elem_bytes=a.dtype.itemsize,
-                                  vmem_bytes=vmem)
-    ws = 4 * N * N * a.dtype.itemsize          # 3 in planes + 1 out plane
-    if ws > vmem:
+    if need > vmem:
+        blk = blocking.stencil_blocks(radius, shape, n_arrays=n_arrays,
+                                      elem_bytes=elem_bytes,
+                                      vmem_bytes=vmem)
         raise ValueError(
-            f"N={N}: plane working set {ws/2**20:.0f} MiB exceeds VMEM "
-            f"({vmem/2**20:.0f} MiB); advisor suggests bi={blk.bi}, "
-            f"bj={blk.bj} tiling")
+            f"{name}: N={shape[-1]} planes need {need / 2**20:.2f} MiB of "
+            f"VMEM (double-buffered pipeline + temporaries), more than the "
+            f"chip's {vmem / 2**20:.0f} MiB; the blocking advisor suggests "
+            f"bk={blk.bk}, bj={blk.bj}, bi={blk.bi} tiles")
+
+
+def stencil3d7pt(a, coeffs, interpret: bool | None = None):
+    """Validates the 3-plane working set (3D layer condition) fits VMEM."""
+    N = a.shape[-1]
+    _check_vmem("stencil3d7pt", _s7.vmem_bytes(N, a.dtype.itemsize), 1,
+                a.shape, 2, a.dtype.itemsize)
     return _s7.stencil3d7pt(a, jnp.asarray(coeffs, a.dtype),
                             interpret=interpret)
 
 
-def longrange3d(u, v, roc, coeffs, interpret: bool = True):
+def longrange3d(u, v, roc, coeffs, interpret: bool | None = None):
     """Validates the 11-plane (9 V + U + ROC) working set fits VMEM."""
-    M, N, _ = u.shape
-    vmem = _v5e().vmem_bytes
-    ws = 12 * N * N * u.dtype.itemsize         # + 1 out plane
-    if ws > vmem:
-        blk = blocking.stencil_blocks(4, (M, N, N), n_arrays=3,
-                                      elem_bytes=u.dtype.itemsize,
-                                      vmem_bytes=vmem)
-        raise ValueError(
-            f"N={N}: working set {ws/2**20:.0f} MiB exceeds VMEM; "
-            f"advisor: {blk}")
+    N = u.shape[-1]
+    _check_vmem("longrange3d", _lr.vmem_bytes(N, u.dtype.itemsize), 4,
+                u.shape, 3, u.dtype.itemsize)
     return _lr.longrange3d(u, v, roc, jnp.asarray(coeffs, u.dtype),
                            interpret=interpret)
 
 
-def flash_attention(q, k, v, causal: bool = True, interpret: bool = True,
-                    q_offset: int | None = None):
-    """Block sizes from the LC advisor; kv heads broadcast for GQA callers."""
-    b, h, sq, d = q.shape
-    skv = k.shape[2]
-    tiles = blocking.attention_tiles(sq, skv, d, q.dtype.itemsize,
+def flash_blocks(sq: int, skv: int, d: int, elem_bytes: int
+                 ) -> tuple[int, int]:
+    """``(block_q, block_kv)`` from the LC advisor, clamped to divisors of
+    the sequence lengths."""
+    tiles = blocking.attention_tiles(sq, skv, d, elem_bytes,
                                      _v5e().vmem_bytes)
     bq = max(8, min(tiles.bq, sq))
     bkv = max(128 if skv % 128 == 0 else skv, 1) if skv < 128 else \
@@ -70,6 +70,15 @@ def flash_attention(q, k, v, causal: bool = True, interpret: bool = True,
         bq //= 2
     while skv % bkv:
         bkv //= 2
+    return bq, bkv
+
+
+def flash_attention(q, k, v, causal: bool = True,
+                    interpret: bool | None = None,
+                    q_offset: int | None = None):
+    """Block sizes from the LC advisor; kv heads broadcast for GQA callers."""
+    bq, bkv = flash_blocks(q.shape[2], k.shape[2], q.shape[3],
+                           q.dtype.itemsize)
     return _fa.flash_attention(q, k, v, causal=causal, block_q=bq,
                                block_kv=bkv, q_offset=q_offset,
                                interpret=interpret)

@@ -40,7 +40,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
         donate_args = {"train": (0, 1), "prefill": (2,)}.get(
             cell.shape.kind, (1,))
 
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.fn,
                          in_shardings=shard(mesh, cell.in_specs),
                          out_shardings=shard(mesh, cell.out_specs),

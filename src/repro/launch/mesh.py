@@ -1,19 +1,20 @@
-"""Mesh construction. A FUNCTION, not a module-level constant, so importing
+"""Mesh construction. FUNCTIONS, not module-level constants, so importing
 this module never touches jax device state (the dry-run entry point must set
-XLA_FLAGS before the first jax call)."""
+XLA_FLAGS before the first jax call).
+
+Every mesh in the repo is built here, with ``AxisType.Auto`` axes: a bare
+``jax.make_mesh`` makes ``Explicit`` axes, under which the sharding-rule
+constraints (``with_sharding_constraint`` on logical axes) are refused."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def _make_mesh(shape, axes):
-    # jax.sharding.AxisType only exists on newer jax; older versions default
-    # every axis to Auto anyway, so omit the kwarg there.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """A mesh of ``shape`` over ``axes`` with every axis ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,10 +26,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
-    """Small mesh over however many (host-platform) devices exist — used by
-    tests and the CPU examples."""
-    return _make_mesh((data, model), ("data", "model"))
+    """Small (data, model) mesh over the local devices — the 2x2 serving
+    mesh of one four-chip host, and the forced host devices of the tests
+    and CPU examples."""
+    return make_mesh((data, model), ("data", "model"))

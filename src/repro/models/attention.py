@@ -24,10 +24,13 @@ def gqa_recs(cfg, bias: bool = False) -> dict[str, PRec]:
     h = pad_heads(cfg.n_heads, cfg.tp)
     kv = pad_heads(cfg.n_kv_heads, cfg.tp)
     d, hd = cfg.d_model, cfg.head_dim
+    # fan-in is d: the default (shape[-2]) would read the head count and
+    # give q/k entries variance d/h, scores of std ~hd, and a near-one-hot
+    # softmax whose choices flip on any rounding difference
     recs = {
-        "wq": PRec((d, h, hd), ("embed", "heads", "hd")),
-        "wk": PRec((d, kv, hd), ("embed", "kv", "hd")),
-        "wv": PRec((d, kv, hd), ("embed", "kv", "hd")),
+        "wq": PRec((d, h, hd), ("embed", "heads", "hd"), scale=d ** -0.5),
+        "wk": PRec((d, kv, hd), ("embed", "kv", "hd"), scale=d ** -0.5),
+        "wv": PRec((d, kv, hd), ("embed", "kv", "hd"), scale=d ** -0.5),
         "wo": PRec((h, hd, d), ("heads", "hd", "embed"),
                    scale=(h * hd) ** -0.5),
         "ln": PRec((d,), ("embed",), init="zeros"),
@@ -51,11 +54,14 @@ def mla_recs(cfg) -> dict[str, PRec]:
     return {
         "wq_a": PRec((d, m.q_lora), ("embed", "latent")),
         "q_ln": PRec((m.q_lora,), ("latent",), init="zeros"),
-        "wq_b": PRec((m.q_lora, h, nope + rope_d), ("latent", "heads", "hd")),
+        "wq_b": PRec((m.q_lora, h, nope + rope_d), ("latent", "heads", "hd"),
+                     scale=m.q_lora ** -0.5),
         "wkv_a": PRec((d, m.kv_lora + rope_d), ("embed", "latent")),
         "kv_ln": PRec((m.kv_lora,), ("latent",), init="zeros"),
-        "wk_b": PRec((m.kv_lora, h, nope), ("latent", "heads", "hd")),
-        "wv_b": PRec((m.kv_lora, h, m.v_dim), ("latent", "heads", "hd")),
+        "wk_b": PRec((m.kv_lora, h, nope), ("latent", "heads", "hd"),
+                     scale=m.kv_lora ** -0.5),
+        "wv_b": PRec((m.kv_lora, h, m.v_dim), ("latent", "heads", "hd"),
+                     scale=m.kv_lora ** -0.5),
         "wo": PRec((h, m.v_dim, d), ("heads", "hd", "embed"),
                    scale=(h * m.v_dim) ** -0.5),
         "ln": PRec((d,), ("embed",), init="zeros"),

@@ -12,6 +12,7 @@ generator.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import queue
 import time
@@ -19,7 +20,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import materialize
+from repro.models.common import materialize, shardings
 
 
 def make_caches(model, batch: int, max_len: int, key=None):
@@ -40,11 +41,20 @@ class Request:
 
 
 class Engine:
-    """Static-batch generation engine with jitted prefill + decode."""
+    """Static-batch generation engine with jitted prefill + decode.
 
-    def __init__(self, model, params, max_len: int, rule=None):
-        self.model, self.params, self.max_len = model, params, max_len
-        self.rule = rule
+    With a ``mesh`` (and the sharding ``rule`` the model's constraints
+    use), params and caches are placed by the rule's ``PartitionSpec``s
+    and every step runs under that mesh. Caches are donated to the step
+    that updates them, so one copy of the KV cache is live at a time."""
+
+    def __init__(self, model, params, max_len: int, rule=None, mesh=None):
+        self.model, self.max_len = model, max_len
+        self.rule, self.mesh = rule, mesh
+        if mesh is not None:
+            params = jax.device_put(
+                params, shardings(model.param_recs(), mesh, rule))
+        self.params = params
 
         def _prefill(params, batch, caches):
             return model.prefill(params, batch, caches, rule=rule)
@@ -52,8 +62,22 @@ class Engine:
         def _decode(params, caches, tokens, pos):
             return model.decode_step(params, caches, tokens, pos, rule=rule)
 
-        self.prefill = jax.jit(_prefill)
-        self.decode = jax.jit(_decode)
+        self.prefill = jax.jit(_prefill, donate_argnums=2)
+        self.decode = jax.jit(_decode, donate_argnums=1)
+
+    def mesh_context(self):
+        """The context the engine's steps run in: its mesh, if any."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
+
+    def new_caches(self, batch: int):
+        """Zero caches for ``batch`` rows, placed on the engine's mesh."""
+        caches = make_caches(self.model, batch, self.max_len)
+        if self.mesh is None:
+            return caches
+        recs = self.model.cache_recs(batch, self.max_len)
+        return jax.device_put(caches, shardings(recs, self.mesh, self.rule))
 
     def _sample(self, logits, temperature: float, key):
         if temperature == 0.0:
@@ -69,19 +93,27 @@ class Engine:
         b, s0 = tokens.shape
         assert s0 + n_new <= self.max_len, (s0, n_new, self.max_len)
         key = jax.random.PRNGKey(0) if key is None else key
-        caches = make_caches(self.model, b, self.max_len)
-        batch = {"tokens": tokens, **(extras or {})}
-        logits, caches = self.prefill(self.params, batch, caches)
-        out = []
-        tok = self._sample(logits, temperature, key)
-        out.append(tok)
-        for i in range(1, n_new):
-            key, sub = jax.random.split(key)
-            logits, caches = self.decode(self.params, caches, tok,
-                                         jnp.int32(s0 + i - 1))
-            tok = self._sample(logits, temperature, sub)
+        with self.mesh_context():
+            caches = self.new_caches(b)
+            batch = {"tokens": tokens, **(extras or {})}
+            logits, caches = self.prefill(self.params, batch, caches)
+            out = []
+            tok = self._sample(logits, temperature, key)
             out.append(tok)
+            for i in range(1, n_new):
+                key, sub = jax.random.split(key)
+                logits, caches = self.decode(self.params, caches, tok,
+                                             jnp.int32(s0 + i - 1))
+                tok = self._sample(logits, temperature, sub)
+                out.append(tok)
         return jnp.concatenate(out, axis=1)
+
+
+def left_pad(prompts: list[list[int]]):
+    """(b, longest) int32 batch of ``prompts``, left-padded with token 0."""
+    s_max = max(len(p) for p in prompts)
+    return jnp.asarray([[0] * (s_max - len(p)) + p for p in prompts],
+                       jnp.int32)
 
 
 class BatchedServer:
@@ -115,11 +147,9 @@ class BatchedServer:
                     break
             if not bucket:
                 break
-            s_max = max(len(r.tokens) for r in bucket)
             n_new = max(r.max_new for r in bucket)
-            toks = jnp.asarray([([0] * (s_max - len(r.tokens)) + r.tokens)
-                                for r in bucket], jnp.int32)
-            gen = self.engine.generate(toks, n_new)
+            gen = self.engine.generate(left_pad([r.tokens for r in bucket]),
+                                       n_new)
             self._served.append(len(bucket))
             for i, r in enumerate(bucket):
                 r.result = [int(t) for t in gen[i][:r.max_new]]

@@ -12,11 +12,11 @@ from .measure import TimedRun, measure_candidate, robust_median, time_closure
 from .report import CandidateOutcome, TuneReport
 from .space import (SPACE_REGISTRY, Candidate, CandidateSpace, Prediction,
                     register_space, resolve_space)
-from .tuner import tune
+from .tuner import TuneFailed, tune
 
 __all__ = [
     "Candidate", "CandidateOutcome", "CandidateSpace", "Prediction",
-    "SPACE_REGISTRY", "TimedRun", "TuneReport", "apply_calibration",
+    "SPACE_REGISTRY", "TimedRun", "TuneFailed", "TuneReport", "apply_calibration",
     "derive_calibration", "machine_yaml_path", "measure_candidate",
     "prediction_error", "register_space", "resolve_space",
     "robust_median", "time_closure", "tune",

@@ -23,11 +23,12 @@ Factors land in a ``calibration:`` section of the machine YAML via
 behind the opt-in ``calibrated=True`` flag, so every existing golden
 stays bit-identical until a caller asks for calibrated numbers.
 
-Measured walls in this repo come from interpret-mode Pallas on CPU, so
-derived factors are large (the analytic model predicts TPU silicon, the
-timer measures a Python interpreter).  That is expected and documented
-(docs/autotune.md): calibration corrects systematic bias of whatever
-*measurement channel* feeds it; on real TPUs the factors land near 1.
+Measured walls are compiled kernels on a TPU backend and the Pallas
+interpreter anywhere else (the report's ``options.interpret`` says
+which).  Factors derived from an interpreter run are large — the
+analytic model predicts TPU silicon, the timer measures a Python
+interpreter — and describe that measurement channel, not the chip; only
+factors from a TPU run belong in a TPU machine file.
 """
 from __future__ import annotations
 

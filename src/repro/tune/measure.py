@@ -7,12 +7,14 @@ fences), so a stray GC pause or container hiccup cannot crown the wrong
 candidate.  Timing closes over ``jax.block_until_ready`` (built into the
 space's runner), so async dispatch cannot fake a win either.
 
-By default every candidate runs in its own spawned subprocess with a hard
-timeout: a candidate that crashes the Pallas lowering, OOMs, or hangs is
-recorded as a failed :class:`TimedRun` and the tune run continues — one
-bad point never kills the sweep.  ``isolate=False`` times in-process
-(fast, used by tests and the benchmark's smoke path) at the cost of
-timeout protection.
+Off the TPU, every candidate runs by default in its own spawned
+subprocess with a hard timeout: a candidate that crashes the Pallas
+lowering, OOMs, or hangs is recorded as a failed :class:`TimedRun` and the
+tune run continues — one bad point never kills the sweep.
+``isolate=False`` times in-process (fast, used by tests and the
+benchmark's smoke path) at the cost of timeout protection.  On a TPU
+backend candidates are always timed in-process: a chip belongs to one
+process, so a child could not reach it.
 
 Fault injection for tests mirrors ``REPRO_WORKER_FAULT``
 (:mod:`repro.service.workers`): ``REPRO_TUNE_FAULT`` ∈ {``raise``,
@@ -136,7 +138,7 @@ def _maybe_fault(params: dict) -> None:
 
 def _run_inproc(family: str, config: dict, params: dict,
                 machine: Machine, warmup: int, reps: int,
-                interpret: bool) -> TimedRun:
+                interpret: bool | None) -> TimedRun:
     from repro.tune.space import resolve_space
     _maybe_fault(params)
     space = resolve_space(family, machine, **config)
@@ -146,7 +148,7 @@ def _run_inproc(family: str, config: dict, params: dict,
 
 def _child_entry(conn, family: str, config: dict, params: dict,
                  machine: Machine, warmup: int, reps: int,
-                 interpret: bool) -> None:
+                 interpret: bool | None) -> None:
     """Subprocess entry point (module-level for spawn picklability)."""
     try:
         tr = _run_inproc(family, config, params, machine, warmup, reps,
@@ -177,7 +179,7 @@ def _ensure_importable_env() -> tuple[str, str | None]:
 def measure_candidate(family: str, config: dict, params: dict,
                       machine: Machine, *, warmup: int = 1, reps: int = 3,
                       timeout_s: float = 120.0, isolate: bool = True,
-                      retries: int = 1, interpret: bool = True,
+                      retries: int = 1, interpret: bool | None = None,
                       start_method: str | None = None) -> TimedRun:
     """Measure one candidate; never raises for candidate-side failures.
 
@@ -186,9 +188,11 @@ def measure_candidate(family: str, config: dict, params: dict,
     extra attempts after a crash — the failure mode of a bad Pallas
     config (lowering assert, OOM kill, interpreter hang) becomes a
     ``TimedRun(ok=False, ...)`` record.  Timeouts are not retried: a
-    config that hangs once will hang again.
+    config that hangs once will hang again.  On a TPU backend ``isolate``
+    is ignored and the candidate is timed in this process.
     """
-    if not isolate:
+    from repro.kernels.backend import on_tpu
+    if not isolate or on_tpu():
         try:
             return _run_inproc(family, dict(config), dict(params), machine,
                                warmup, reps, interpret)

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import abc
 import dataclasses
+import importlib
 import math
 
 import numpy as np
@@ -99,9 +100,10 @@ class CandidateSpace(abc.ABC):
         to share compiled plans across repeated rankings."""
 
     @abc.abstractmethod
-    def runner(self, params: dict, interpret: bool = True):
+    def runner(self, params: dict, interpret: bool | None = None):
         """A zero-argument closure executing one timed kernel invocation
         for ``params`` (inputs prebuilt, ``block_until_ready`` inside).
+        ``interpret=None`` compiles on a TPU and interprets elsewhere.
         Imports jax lazily: prediction-only callers never pay for it."""
 
 
@@ -253,7 +255,7 @@ class FlashAttentionSpace(CandidateSpace):
             out.append(Prediction(max(t_compute, t_hbm), bound=bound))
         return out
 
-    def runner(self, params: dict, interpret: bool = True):
+    def runner(self, params: dict, interpret: bool | None = None):
         import jax
         import jax.numpy as jnp
 
@@ -294,10 +296,9 @@ class _StencilSpace(CandidateSpace):
     instead, scoring each block by its chip-level saturated performance
     at the target core count."""
 
-    #: subclasses: trace source, halo radius, plane count for VMEM check
+    #: subclasses: trace source, halo radius
     TRACE = ""
     RADIUS = 1
-    PLANES = 4
     DEFAULTS = {"m": 16, "n_min": 32, "n_max": 128, "n_step": 16,
                 "cores": 1}
 
@@ -353,7 +354,8 @@ class _StencilSpace(CandidateSpace):
             score = {p["N"]: s for p, s in gs.ranking}
         unit = gs.best_result.unit_iterations
         clock = m.clock_hz or 1e9
-        vmem_limit = (m.vmem_bytes or 2 ** 27) * VMEM_BUDGET
+        vmem = m.vmem_bytes or 2 ** 27
+        kernel_mod = importlib.import_module(f"repro.kernels.{self.family}")
         # binding term at each point, for calibration attribution: the
         # exact result at the winner names whether compute or a transfer
         # term dominates (LC regimes are piecewise-constant, so the
@@ -365,12 +367,12 @@ class _StencilSpace(CandidateSpace):
         out: list[Prediction] = []
         for cand in cands:
             n = int(cand.config["n"])
-            ws = self.PLANES * n * n * 4.0        # fp32 measurement planes
-            if ws > vmem_limit:
+            ws = kernel_mod.vmem_bytes(n, 4)      # fp32 measurement planes
+            if ws > vmem:
                 out.append(Prediction(
                     math.inf, feasible=False,
-                    reason=f"{self.PLANES} planes of {n}x{n} exceed "
-                           f"{VMEM_BUDGET:.0%} of VMEM"))
+                    reason=f"{n}x{n} planes need {ws / 2**20:.1f} MiB, "
+                           f"more than VMEM"))
                 continue
             cy_per_unit = score[n]
             secs = (cy_per_unit / unit / clock * self._points(n)
@@ -384,9 +386,8 @@ class Stencil7ptSpace(_StencilSpace):
     family = "stencil3d7pt"
     TRACE = "trace:stencil3d7pt"
     RADIUS = 1
-    PLANES = 4          # 3 input planes + 1 output plane resident
 
-    def runner(self, params: dict, interpret: bool = True):
+    def runner(self, params: dict, interpret: bool | None = None):
         import jax
         import jax.numpy as jnp
 
@@ -409,9 +410,8 @@ class LongRange3DSpace(_StencilSpace):
     family = "longrange3d"
     TRACE = "trace:longrange3d"
     RADIUS = 4
-    PLANES = 12         # 9 V planes + U + ROC + out
 
-    def runner(self, params: dict, interpret: bool = True):
+    def runner(self, params: dict, interpret: bool | None = None):
         import jax
         import jax.numpy as jnp
 

@@ -5,7 +5,9 @@ both land here): resolve the family's :class:`~repro.tune.space
 .CandidateSpace`, predict every candidate in one batched call, measure
 the top-k plus the shipped default with real timers, pick the fastest
 measured candidate, and derive calibration factors from the
-measured/predicted ratios.  With a :class:`~repro.service
+measured/predicted ratios.  A measured run whose shipped default fails
+to measure raises :class:`TuneFailed` instead of falling back to the
+prediction.  With a :class:`~repro.service
 .AnalysisService` attached, whole reports persist in the result store
 under kind ``"tune"`` — a warm replay decodes from disk without
 recomputing or re-measuring.
@@ -26,6 +28,11 @@ from .space import resolve_space
 KEEP_PREDICTED = 32
 
 
+class TuneFailed(RuntimeError):
+    """Measuring was asked for and the shipped default did not measure, so
+    there is no measured baseline to choose against."""
+
+
 def _freeze(d: dict) -> tuple:
     return tuple(sorted(d.items()))
 
@@ -33,7 +40,7 @@ def _freeze(d: dict) -> tuple:
 def tune(family: str, machine: Machine | str, *, config: dict | None = None,
          top_k: int = 4, measure: bool = True, warmup: int = 1,
          reps: int = 3, timeout_s: float = 120.0, isolate: bool = True,
-         retries: int = 1, interpret: bool = True, session=None,
+         retries: int = 1, interpret: bool | None = None, session=None,
          service=None, keep_predicted: int = KEEP_PREDICTED) -> TuneReport:
     """Autotune ``family`` on ``machine``; returns a :class:`TuneReport`.
 
@@ -46,9 +53,14 @@ def tune(family: str, machine: Machine | str, *, config: dict | None = None,
     carrying ``calibration.time[family]`` (from a previous
     ``--apply-calibration``) has that factor folded into the predictions,
     so recalibrated predictions track measurements more closely.
+    ``interpret=None`` times compiled kernels on a TPU and the Pallas
+    interpreter elsewhere; the report records the resolved value.
     """
     from repro.core import api
     mach = api.resolve_machine(machine)
+    if measure:
+        from repro.kernels.backend import resolve_interpret
+        interpret = resolve_interpret(interpret)
     config = dict(config or {})
     if service is not None:
         key = ("tune", family, mach.fingerprint, _freeze(config),
@@ -110,6 +122,12 @@ def tune(family: str, machine: Machine | str, *, config: dict | None = None,
                 params=cand.config,
                 status=STATUS_OK if tr.ok else STATUS_FAILED,
                 predicted_s=_pred_s(p), bound=p.bound, measured=tr)
+
+    if measure and outcomes.get(default) is not None \
+            and outcomes[default].status != STATUS_OK:
+        raise TuneFailed(
+            f"{family}: the default candidate {default.config} did not "
+            f"measure: {outcomes[default].measured.error}")
 
     # chosen: fastest measured candidate, else the predicted best
     measured_ok = [(c, o) for c, o in outcomes.items()

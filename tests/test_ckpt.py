@@ -59,14 +59,15 @@ def test_elastic_reshard(tmp_path, devices8):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import ckpt
+from repro.launch.mesh import make_local_mesh
 
 w = jnp.arange(64*32, dtype=jnp.float32).reshape(64, 32)
-mesh1 = jax.make_mesh((2, 4), ("data", "model"))
+mesh1 = make_local_mesh(data=2, model=4)
 sharded = jax.device_put(w, NamedSharding(mesh1, P("data", "model")))
 ckpt.save(r"{tmp_path}", 1, {{"w": sharded}})
 
 for shape in [(4, 2), (8, 1), (1, 1)]:
-    mesh2 = jax.make_mesh(shape, ("data", "model"))
+    mesh2 = make_local_mesh(*shape)
     sh = {{"w": NamedSharding(mesh2, P("data", "model"))}}
     out, _ = ckpt.restore(r"{tmp_path}", 1, {{"w": w}}, shardings=sh)
     np.testing.assert_array_equal(np.asarray(out["w"]), np.asarray(w))

@@ -8,15 +8,16 @@ def test_compressed_psum_error_feedback(devices8):
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.dist import CompressionState, compressed_psum_tree
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 key = jax.random.PRNGKey(0)
 g = jax.random.normal(key, (8, 64, 32))     # per-device gradient slices
 
 @partial(shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
-         out_specs=(P(None), P("data")), check_rep=False)
+         out_specs=(P(None), P("data")), check_vma=False)
 def cpsum(gl, el):
     out, err = compressed_psum_tree(gl[0], el[0], "data")
     return out[None], err[None]
@@ -45,8 +46,9 @@ def test_gpipe_matches_sequential(devices8):
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.dist import gpipe
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = make_mesh((8,), ("pod",))
 P_stages, D, B = 8, 16, 32
 key = jax.random.PRNGKey(0)
 ws = jax.random.normal(key, (P_stages, D, D)) * 0.3
@@ -85,6 +87,7 @@ def test_sharded_train_step_small_mesh(devices8):
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
 from repro.launch.cell import rule_for, batch_specs, shard
+from repro.launch.mesh import make_local_mesh
 from repro.models.common import materialize, spec_tree
 from repro.models.lm import LM
 from repro.optim import OptConfig, adamw_init
@@ -94,7 +97,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 cfg = configs.reduced(configs.get_config("granite-8b"))
 model = LM(cfg)
 # (2, 2): the reduced config has 2 kv heads, so model axis must divide 2
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_local_mesh(data=2, model=2)
 shape = configs.SHAPES["train_4k"]
 rule = rule_for(cfg, shape, multi_pod=False)
 tcfg = TrainConfig(opt=OptConfig(lr=1e-3), warmup_steps=1, total_steps=10)
@@ -108,7 +111,7 @@ batch = {"tokens": toks, "labels": jnp.roll(toks, -1, 1)}
 step0 = jax.jit(make_train_step(model, tcfg))
 _, _, m0 = step0(params, opt, batch, jnp.int32(0))
 
-with mesh:
+with jax.set_mesh(mesh):
     step1 = jax.jit(make_train_step(model, tcfg, rule=rule))
     p = jax.device_put(params, shard(mesh, spec_tree(model.param_recs(), rule)))
     _, _, m1 = step1(p, opt, batch, jnp.int32(0))

@@ -22,7 +22,7 @@ mesh = make_production_mesh(multi_pod={multi_pod})
 assert mesh.devices.size == {512 if multi_pod else 256}
 for arch, shape in {CELLS!r}:
     cell = build_cell(arch, shape, multi_pod={multi_pod})
-    with mesh:
+    with jax.set_mesh(mesh):
         jax.jit(cell.fn, in_shardings=shard(mesh, cell.in_specs),
                 out_shardings=shard(mesh, cell.out_specs)).lower(
             *cell.abstract_args)

@@ -91,8 +91,9 @@ def test_sharded_program_collectives(devices8):
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import hlo_analysis as H
+from repro.launch.mesh import make_local_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_local_mesh(data=2, model=4)
 
 def f(w, x):
     y = x @ w                                   # contracting dim sharded
@@ -100,7 +101,7 @@ def f(w, x):
 
 w = jax.ShapeDtypeStruct((128, 64), jnp.float32)
 x = jax.ShapeDtypeStruct((32, 128), jnp.float32)
-with mesh:
+with jax.set_mesh(mesh):
     c = jax.jit(f, in_shardings=(NamedSharding(mesh, P("model", None)),
                                  NamedSharding(mesh, P("data", "model")))
                 ).lower(w, x).compile()

@@ -166,3 +166,47 @@ class TestFlashBlockValidation:
         out = raw_flash(q, k, v, block_q=128, block_kv=128)
         want = ref.attention(q, k, v, causal=True)
         np.testing.assert_allclose(out, want, rtol=2e-3, atol=2e-3)
+
+
+class TestPlatformAndVmem:
+    """Interpret mode follows the platform; the wrappers count the VMEM
+    the plane pipeline holds and refuse what the chip cannot hold."""
+
+    def test_interpret_resolves_from_platform(self):
+        from repro.kernels.backend import on_tpu, resolve_interpret
+        assert resolve_interpret(None) is (not on_tpu())
+        assert resolve_interpret(None) is True          # CPU test backend
+        assert resolve_interpret(False) is False
+        assert resolve_interpret(True) is True
+
+    def test_padded_plane_bytes(self):
+        assert blocking.padded_plane_bytes(8, 128, 4) == 8 * 128 * 4
+        assert blocking.padded_plane_bytes(1015, 1015, 4) == \
+            1016 * 1024 * 4
+        assert blocking.padded_plane_bytes(3, 3, 4) == 8 * 128 * 4
+        # two inputs + output, double-buffered, one temporary, headroom
+        assert blocking.plane_pipeline_vmem(2, 1, 8, 128, 4) == \
+            7 * 8 * 128 * 4 + blocking.PLANE_VMEM_HEADROOM
+
+    def test_longrange_refused_before_compile(self):
+        """N=1040 needs 133.5 MiB: refused by the wrapper while tracing,
+        with the advisor's suggestion, before any kernel is built."""
+        spec = jax.ShapeDtypeStruct((9, 1040, 1040), jnp.float32)
+        c = jax.ShapeDtypeStruct((5,), jnp.float32)
+        with pytest.raises(ValueError,
+                           match=r"133\.54 MiB.*advisor suggests"):
+            jax.eval_shape(longrange3d, spec, spec, spec, c)
+        ok = jax.ShapeDtypeStruct((9, 1024, 1024), jnp.float32)
+        assert jax.eval_shape(longrange3d, ok, ok, ok, c).shape == ok.shape
+
+    @pytest.mark.parametrize("n", [8, 128, 1015, 1024])
+    def test_kernel_vmem_counts_cover_compiler(self, n):
+        """The counts stay above what the v5e compiler was found to
+        allocate: 10.52 planes (stencil3d7pt) and 28.99 planes plus
+        nothing more than 1 MiB (longrange3d)."""
+        import importlib
+        s7 = importlib.import_module("repro.kernels.stencil3d7pt")
+        lr = importlib.import_module("repro.kernels.longrange3d")
+        plane = blocking.padded_plane_bytes(n, n, 4)
+        assert s7.vmem_bytes(n, 4) >= 10.52 * plane
+        assert lr.vmem_bytes(n, 4) >= 28.99 * plane

@@ -449,6 +449,14 @@ class TestCalibratedModels:
 # CLI
 # ----------------------------------------------------------------------
 
+@pytest.fixture(autouse=True)
+def _no_checkout_compile_cache(monkeypatch):
+    """A measuring ``repro tune`` turns on the compile cache; keep the
+    suite's compiles out of the checkout's cache directory."""
+    from repro.launch import compile_cache
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: None)
+
+
 class TestTuneCLI:
     def _run(self, *argv):
         from repro.cli import main
@@ -480,6 +488,58 @@ class TestTuneCLI:
         assert Machine.from_yaml(path).calibration_factor(
             "time", "flash_attention") > 1.0
 
+    def test_only_measuring_turns_on_compile_cache(self, monkeypatch,
+                                                   capsys):
+        from repro.launch import compile_cache
+        calls = []
+        monkeypatch.setattr(compile_cache, "enable_compile_cache",
+                            lambda: calls.append(1))
+        argv = ("tune", "flash_attention", "-m", "tpu_v5e", "--shape",
+                "seq_q", "256", "--shape", "seq_kv", "256", "--shape",
+                "heads", "1", "--top-k", "1", "--reps", "1", "--no-isolate")
+        assert self._run(*argv, "--no-measure") == 0 and calls == []
+        monkeypatch.setenv("REPRO_TUNE_FAULT", "raise")
+        assert self._run(*argv) == 1 and calls == [1]
+
     def test_unknown_family_exit_code(self, capsys):
         assert self._run("tune", "nope", "-m", "tpu_v5e",
                          "--no-measure") == 2
+
+
+# ----------------------------------------------------------------------
+# platform: the interpreter off the TPU, one process on it
+# ----------------------------------------------------------------------
+
+class TestPlatform:
+    def test_report_records_resolved_interpret(self):
+        rep = tune("flash_attention", V5E, config=TINY, top_k=1, reps=1,
+                   isolate=False)
+        assert rep.options["interpret"] is True      # CPU backend
+
+    def test_tpu_backend_measures_in_process(self, toy_space, monkeypatch):
+        """With a TPU backend ``isolate`` is ignored: the toy family is
+        registered only in this process, so a spawned child would fail."""
+        import repro.kernels.backend as backend
+        monkeypatch.setattr(backend, "on_tpu", lambda: True)
+        tr = measure_candidate("toy", {}, {"k": 1}, V5E, isolate=True,
+                               reps=2)
+        assert tr.ok and len(tr.samples) == 2 and tr.retries == 0
+
+    def test_unmeasured_default_fails_the_run(self, monkeypatch):
+        from repro.tune import TuneFailed
+        monkeypatch.setenv("REPRO_TUNE_FAULT", "raise")
+        monkeypatch.setenv("REPRO_TUNE_FAULT_MATCH", "block_q=128")
+        with pytest.raises(TuneFailed, match="default candidate"):
+            tune("flash_attention", V5E, config=TINY, top_k=1, reps=1,
+                 isolate=False)
+
+    def test_cli_exits_nonzero_when_default_fails(self, monkeypatch,
+                                                  capsys):
+        from repro.cli import main
+        monkeypatch.setenv("REPRO_TUNE_FAULT", "raise")
+        rc = main(["tune", "flash_attention", "-m", "tpu_v5e",
+                   "--shape", "seq_q", "256", "--shape", "seq_kv", "256",
+                   "--shape", "heads", "1", "--top-k", "1", "--reps", "1",
+                   "--no-isolate"])
+        assert rc == 1
+        assert "did not measure" in capsys.readouterr().err
