@@ -154,6 +154,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
         functools.partial(_kernel, scale=1.0 / math.sqrt(d), causal=causal,
                           q_offset=q_offset, block_q=block_q,
                           block_kv=block_kv),
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda g, qi, ki: (g, qi, 0)),

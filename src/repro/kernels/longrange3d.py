@@ -108,6 +108,7 @@ def longrange3d(u, v, roc, coeffs, *, interpret: bool | None = None):
     args = [v] * 9 + [u, roc, coeffs]
     return pl.pallas_call(
         _kernel,
+        name="longrange3d",
         grid=(M,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, N, N), lambda k: (k, 0, 0)),

@@ -92,6 +92,7 @@ def stencil3d7pt(a, coeffs, *, interpret: bool | None = None):
 
     return pl.pallas_call(
         _kernel,
+        name="stencil3d7pt",
         grid=(M,),
         in_specs=[shifted(-1), shifted(0), shifted(+1),
                   pl.BlockSpec(memory_space=pltpu.SMEM)],

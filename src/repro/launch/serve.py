@@ -20,11 +20,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
+from repro import configs, obs
 from repro.models.common import materialize
 from repro.models.lm import LM
 from repro.serve import Engine
-from repro.serve.engine import BatchedServer, Request, left_pad
+from repro.serve.engine import BatchedServer, Request, ServeCounts, left_pad
+
+#: the spans of one engine batch, by the phase each times
+PHASES = {"prefill": "serve.prefill", "decode": "serve.decode_step",
+          "readback": "serve.readback"}
 
 
 @dataclasses.dataclass
@@ -33,6 +37,7 @@ class ServeResult:
     seconds: float              # host clock, submit to last token ready
     tokens: int                 # generated tokens
     batches: list[int]          # requests per engine batch
+    counts: ServeCounts         # the server's counters
 
 
 def build(arch: str, *, reduced: bool = False):
@@ -68,9 +73,23 @@ def serve(engine: Engine, requests: list[Request], *,
         server.submit(r)
     done = server.drain()           # token ids reach the host: all ready
     dt = time.perf_counter() - t0
+    batches = [0] * server.counts.batches
+    for r in done:
+        batches[r.batch] += 1
     return ServeResult(done=done, seconds=dt,
                        tokens=sum(len(r.result) for r in done),
-                       batches=list(server._served))
+                       batches=batches, counts=server.counts)
+
+
+def phase_seconds(since_ns: int) -> dict[str, float]:
+    """Seconds of each phase of :data:`PHASES`, summed over the spans
+    that started at ``time.perf_counter_ns()`` = ``since_ns`` or later."""
+    out = dict.fromkeys(PHASES, 0.0)
+    names = {v: k for k, v in PHASES.items()}
+    for r in obs.spans():
+        if r.name in names and r.start_ns >= since_ns:
+            out[names[r.name]] += (r.end_ns - r.start_ns) * 1e-9
+    return out
 
 
 def replay_logits(engine: Engine, requests: list[Request],
@@ -155,11 +174,15 @@ def main(argv=None):
     reqs = make_requests(cfg.vocab, args.requests, tuple(args.prompt_len),
                          args.max_new)
     engine = Engine(model, params, max_len=args.max_len)
+    t0 = time.perf_counter_ns()
     res = serve(engine, reqs, batch_size=args.batch_size)
     print(f"[serve] {args.arch}{' reduced' if args.reduced else ''}: "
           f"{len(res.done)} requests, {res.tokens} tokens in "
           f"{res.seconds:.2f}s ({res.tokens / res.seconds:.1f} tok/s, "
           f"compiles included), batches={res.batches}")
+    print(f"  counts: {dataclasses.asdict(res.counts)}")
+    print("  phases: " + ", ".join(f"{k} {v:.3f}s" for k, v in
+                                   phase_seconds(t0).items()))
     for r in res.done[:3]:
         print(f"  req {r.uid}: {len(r.tokens)} prompt tokens -> "
               f"{r.result[:8]}...")

@@ -185,36 +185,37 @@ def gqa_apply(p, x, cfg, kind: str = "causal", positions=None, cache=None,
     kv_pos = None
     q_pos = positions[0] if positions.ndim == 2 else positions
     if cache is not None:
-        ck, cv = cache["k"], cache["v"]
-        W = ck.shape[1]
-        if "pos" in cache:
-            # ring buffer (local-window layers): slot = position mod W
-            cp = cache["pos"]
-            if s >= W:       # prefill longer than the window: keep the tail
-                ck = k[:, -W:].astype(ck.dtype)
-                cv = v[:, -W:].astype(cv.dtype)
-                cp = q_pos[-W:]
-                cache = {"k": ck, "v": cv, "pos": cp}
-                # attention itself sees the FULL in-call k/v (early queries
-                # need their own chunk, which the ring has already evicted)
-                kv_pos = q_pos
-            else:            # decode / short prefill (no intra-call wrap)
-                slot = (pos if s == 1 else pos) % W
+        with jax.named_scope("kv_update"):
+            ck, cv = cache["k"], cache["v"]
+            W = ck.shape[1]
+            if "pos" in cache:
+                # ring buffer (local-window layers): slot = position mod W
+                cp = cache["pos"]
+                if s >= W:   # prefill longer than the window: keep the tail
+                    ck = k[:, -W:].astype(ck.dtype)
+                    cv = v[:, -W:].astype(cv.dtype)
+                    cp = q_pos[-W:]
+                    cache = {"k": ck, "v": cv, "pos": cp}
+                    # attention itself sees the FULL in-call k/v (early queries
+                    # need their own chunk, which the ring has already evicted)
+                    kv_pos = q_pos
+                else:        # decode / short prefill (no intra-call wrap)
+                    slot = (pos if s == 1 else pos) % W
+                    ck = jax.lax.dynamic_update_slice(
+                        ck, k.astype(ck.dtype), (0, slot, 0, 0))
+                    cv = jax.lax.dynamic_update_slice(
+                        cv, v.astype(cv.dtype), (0, slot, 0, 0))
+                    cp = jax.lax.dynamic_update_slice(cp, q_pos, (slot,))
+                    cache = {"k": ck, "v": cv, "pos": cp}
+                    k, v, kv_pos = ck, cv, cp
+            else:
                 ck = jax.lax.dynamic_update_slice(
-                    ck, k.astype(ck.dtype), (0, slot, 0, 0))
+                    ck, k.astype(ck.dtype), (0, pos, 0, 0))
                 cv = jax.lax.dynamic_update_slice(
-                    cv, v.astype(cv.dtype), (0, slot, 0, 0))
-                cp = jax.lax.dynamic_update_slice(cp, q_pos, (slot,))
-                cache = {"k": ck, "v": cv, "pos": cp}
-                k, v, kv_pos = ck, cv, cp
-        else:
-            ck = jax.lax.dynamic_update_slice(
-                ck, k.astype(ck.dtype), (0, pos, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cv, v.astype(cv.dtype), (0, pos, 0, 0))
-            k, v = ck, cv
-            kv_len = pos + s
-            cache = {"k": ck, "v": cv}
+                    cv, v.astype(cv.dtype), (0, pos, 0, 0))
+                k, v = ck, cv
+                kv_len = pos + s
+                cache = {"k": ck, "v": cv}
     o = attend(q, k, v, kind, q_pos=q_pos, kv_pos=kv_pos, window=window,
                kv_len=kv_len, rule=rule)
     out = jnp.einsum("bsnh,nhd->bsd", o, p["wo"])
@@ -246,11 +247,13 @@ def mla_apply(p, x, cfg, positions=None, cache=None, pos=None, rule=None):
 
     kv_len, q_start = None, 0
     if cache is not None:
-        cl = jax.lax.dynamic_update_slice(
-            cache["latent"], latent.astype(cache["latent"].dtype), (0, pos, 0))
-        cr = jax.lax.dynamic_update_slice(
-            cache["k_rope"], k_rope.astype(cache["k_rope"].dtype),
-            (0, pos, 0, 0))
+        with jax.named_scope("kv_update"):
+            cl = jax.lax.dynamic_update_slice(
+                cache["latent"], latent.astype(cache["latent"].dtype),
+                (0, pos, 0))
+            cr = jax.lax.dynamic_update_slice(
+                cache["k_rope"], k_rope.astype(cache["k_rope"].dtype),
+                (0, pos, 0, 0))
         latent, k_rope = cl, cr
         cache = {"latent": cl, "k_rope": cr}
         kv_len, q_start = pos + s, pos

@@ -275,10 +275,11 @@ class LM:
                 newc = []
                 for bi, blk in enumerate(_st.blocks):
                     bc = lc[bi] if lc is not None else None
-                    xc, bc = self._apply_block(
-                        blk, lp[bi], xc, rule, cache=bc, pos=pos,
-                        shared=params.get("shared"), enc_out=enc_out,
-                        x_emb=x_emb)
+                    with jax.named_scope(blk.kind):
+                        xc, bc = self._apply_block(
+                            blk, lp[bi], xc, rule, cache=bc, pos=pos,
+                            shared=params.get("shared"), enc_out=enc_out,
+                            x_emb=x_emb)
                     newc.append(bc if bc is not None else {})
                 return xc, newc
 
@@ -356,15 +357,18 @@ class LM:
 
     def _head(self, params, x, rule):
         cfg = self.cfg
-        x = (rms_norm(x, params["final_ln"]) if cfg.norm == "rmsnorm"
-             else layer_norm(x, params["final_ln"], params["final_ln_b"]))
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-        if self.padded_vocab != cfg.vocab:   # mask vocab-padding entries
-            pad_mask = jnp.arange(self.padded_vocab) >= cfg.vocab
-            logits = jnp.where(pad_mask, jnp.float32(-2.0 ** 30).astype(
-                logits.dtype), logits)
-        if rule is not None:
-            logits = constrain(logits, rule, ("batch", None, "act_vocab"))
+        with jax.named_scope("head"):
+            x = (rms_norm(x, params["final_ln"]) if cfg.norm == "rmsnorm"
+                 else layer_norm(x, params["final_ln"],
+                                 params["final_ln_b"]))
+            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+            if self.padded_vocab != cfg.vocab:   # mask vocab-padding
+                pad_mask = jnp.arange(self.padded_vocab) >= cfg.vocab
+                logits = jnp.where(pad_mask, jnp.float32(
+                    -2.0 ** 30).astype(logits.dtype), logits)
+            if rule is not None:
+                logits = constrain(logits, rule,
+                                   ("batch", None, "act_vocab"))
         return logits
 
     # -- serving ----------------------------------------------------------

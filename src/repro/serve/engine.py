@@ -20,6 +20,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models.common import materialize, shardings
 
 
@@ -38,6 +39,7 @@ class Request:
     max_new: int = 16
     result: list[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    batch: int = -1             # the server's id of the batch that served it
 
 
 class Engine:
@@ -88,25 +90,30 @@ class Engine:
             key, jnp.log(probs + 1e-9), axis=-1)[:, None]
 
     def generate(self, tokens, n_new: int, temperature: float = 0.0,
-                 key=None, extras: dict | None = None):
-        """tokens: (b, s0) int32 prompt. Returns (b, n_new) generated ids."""
+                 key=None, extras: dict | None = None) -> list:
+        """tokens: (b, s0) int32 prompt. Returns the generated ids as they
+        are made: ``n_new`` (b, 1) arrays, still on the device. Spans
+        ``serve.prefill`` (new caches, prefill, first sample) and one
+        ``serve.decode_step`` per further token (key split, decode,
+        sample)."""
         b, s0 = tokens.shape
         assert s0 + n_new <= self.max_len, (s0, n_new, self.max_len)
         key = jax.random.PRNGKey(0) if key is None else key
         with self.mesh_context():
-            caches = self.new_caches(b)
-            batch = {"tokens": tokens, **(extras or {})}
-            logits, caches = self.prefill(self.params, batch, caches)
-            out = []
-            tok = self._sample(logits, temperature, key)
-            out.append(tok)
+            with obs.span("serve.prefill"):
+                caches = self.new_caches(b)
+                batch = {"tokens": tokens, **(extras or {})}
+                logits, caches = self.prefill(self.params, batch, caches)
+                tok = self._sample(logits, temperature, key)
+            out = [tok]
             for i in range(1, n_new):
-                key, sub = jax.random.split(key)
-                logits, caches = self.decode(self.params, caches, tok,
-                                             jnp.int32(s0 + i - 1))
-                tok = self._sample(logits, temperature, sub)
+                with obs.span("serve.decode_step", step=i):
+                    key, sub = jax.random.split(key)
+                    logits, caches = self.decode(self.params, caches, tok,
+                                                 jnp.int32(s0 + i - 1))
+                    tok = self._sample(logits, temperature, sub)
                 out.append(tok)
-        return jnp.concatenate(out, axis=1)
+        return out
 
 
 def left_pad(prompts: list[list[int]]):
@@ -114,6 +121,16 @@ def left_pad(prompts: list[list[int]]):
     s_max = max(len(p) for p in prompts)
     return jnp.asarray([[0] * (s_max - len(p)) + p for p in prompts],
                        jnp.int32)
+
+
+@dataclasses.dataclass
+class ServeCounts:
+    """What a :class:`BatchedServer` has served, counted per batch."""
+    batches: int = 0
+    requests: int = 0
+    prompt_tokens: int = 0      # the requests' own prompt tokens
+    pad_tokens: int = 0         # left padding up to each batch's longest
+    new_tokens: int = 0         # tokens the engine made, rows x steps
 
 
 class BatchedServer:
@@ -127,13 +144,19 @@ class BatchedServer:
         self.batch_size = batch_size
         self.max_wait_s = max_wait_s
         self._queue: queue.Queue[Request] = queue.Queue()
-        self._served: list[int] = []    # batch sizes actually used
+        self.counts = ServeCounts()
 
     def submit(self, req: Request):
         self._queue.put(req)
 
     def drain(self) -> list[Request]:
-        """Serve everything currently queued; returns completed requests."""
+        """Serve everything currently queued; returns completed requests.
+
+        Each bucket is one ``serve.batch`` span (:mod:`repro.obs`) around
+        the engine's ``serve.prefill`` and ``serve.decode_step`` spans and a
+        ``serve.readback`` span, which joins the tokens and reads them to
+        the host once the last step is done, so that it times the transfer
+        and not the wait for queued steps; :attr:`counts` adds it up."""
         done = []
         while not self._queue.empty():
             bucket: list[Request] = []
@@ -147,12 +170,26 @@ class BatchedServer:
                     break
             if not bucket:
                 break
+            c = self.counts
             n_new = max(r.max_new for r in bucket)
-            gen = self.engine.generate(left_pad([r.tokens for r in bucket]),
-                                       n_new)
-            self._served.append(len(bucket))
-            for i, r in enumerate(bucket):
-                r.result = [int(t) for t in gen[i][:r.max_new]]
-                r.done = True
+            prompt = [len(r.tokens) for r in bucket]
+            with obs.span("serve.batch", batch=c.batches, rows=len(bucket),
+                          uids=tuple(r.uid for r in bucket),
+                          prompt_len=max(prompt), n_new=n_new):
+                steps = self.engine.generate(
+                    left_pad([r.tokens for r in bucket]), n_new)
+                jax.block_until_ready(steps[-1])
+                with obs.span("serve.readback",
+                              tokens=sum(r.max_new for r in bucket)):
+                    gen = jnp.concatenate(steps, axis=1)
+                    for i, r in enumerate(bucket):
+                        r.result = [int(t) for t in gen[i][:r.max_new]]
+            for r in bucket:
+                r.batch, r.done = c.batches, True
                 done.append(r)
+            c.batches += 1
+            c.requests += len(bucket)
+            c.prompt_tokens += sum(prompt)
+            c.pad_tokens += len(bucket) * max(prompt) - sum(prompt)
+            c.new_tokens += len(bucket) * n_new
         return done

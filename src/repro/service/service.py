@@ -498,7 +498,6 @@ class AnalysisServer:
         self.service = service
         self.batch_size = int(batch_size)
         self._queue: queue.Queue[AnalysisRequest] = queue.Queue()
-        self._served: list[int] = []        # batch sizes actually used
 
     def submit(self, req: AnalysisRequest) -> None:
         if req.kind not in ("analyze", "sweep"):
@@ -526,7 +525,6 @@ class AnalysisServer:
                                 if r.kind == "analyze"
                                 else self.service.sweep, **r.request)
                     for r in bucket]
-            self._served.append(len(bucket))
             for req, fut in zip(bucket, futs):
                 try:
                     req.result = fut.result()

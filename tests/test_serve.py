@@ -12,6 +12,11 @@ from repro.serve import Engine
 from repro.serve.engine import BatchedServer, Request
 
 
+def _generate(eng, toks, *args, **kwargs):
+    """The engine's per-step tokens joined into one (b, n_new) array."""
+    return jnp.concatenate(eng.generate(toks, *args, **kwargs), axis=1)
+
+
 @pytest.fixture(scope="module")
 def setup():
     cfg = configs.reduced(configs.get_config("granite-8b"))
@@ -24,7 +29,7 @@ def test_greedy_matches_forward(setup):
     cfg, model, params = setup
     eng = Engine(model, params, max_len=64)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, cfg.vocab)
-    gen = eng.generate(toks, 5)
+    gen = _generate(eng, toks, 5)
     # teacher-force the full forward over prompt+generated; argmax must
     # reproduce each generated token
     seq = jnp.concatenate([toks, gen], axis=1)
@@ -39,7 +44,7 @@ def test_generated_tokens_in_vocab(setup):
     cfg, model, params = setup
     eng = Engine(model, params, max_len=64)
     toks = jnp.zeros((2, 4), jnp.int32)
-    gen = eng.generate(toks, 8, temperature=1.0)
+    gen = _generate(eng, toks, 8, temperature=1.0)
     assert int(gen.max()) < cfg.vocab       # vocab padding never sampled
     assert gen.shape == (2, 8)
 
@@ -53,15 +58,22 @@ def test_batched_server(setup):
     done = srv.drain()
     assert len(done) == 7
     assert all(len(r.result) == 4 for r in done)
-    assert srv._served == [3, 3, 1]         # bucketed batching
+    sizes = [sum(r.batch == i for r in done)
+             for i in range(srv.counts.batches)]
+    assert sizes == [3, 3, 1]               # bucketed batching
+    assert srv.counts.requests == 7
+    assert srv.counts.prompt_tokens == 21 and srv.counts.pad_tokens == 0
+    assert srv.counts.new_tokens == 28
 
 
 def test_temperature_sampling_reproducible(setup):
     cfg, model, params = setup
     eng = Engine(model, params, max_len=32)
     toks = jnp.zeros((1, 4), jnp.int32)
-    g1 = eng.generate(toks, 6, temperature=0.8, key=jax.random.PRNGKey(7))
-    g2 = eng.generate(toks, 6, temperature=0.8, key=jax.random.PRNGKey(7))
+    g1 = _generate(eng, toks, 6, temperature=0.8,
+                   key=jax.random.PRNGKey(7))
+    g2 = _generate(eng, toks, 6, temperature=0.8,
+                   key=jax.random.PRNGKey(7))
     np.testing.assert_array_equal(np.asarray(g1), np.asarray(g2))
 
 
