@@ -11,6 +11,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from .common import PRec, constrain, layer_norm, pad_heads, rms_norm, rope
 
@@ -158,11 +159,37 @@ def attend(q, k, v, kind: str, q_pos=None, kv_pos=None, window: int = 0,
 
 
 # ----------------------------------------------------------------------
+# KV caches: every layer's rows live in one stacked (L, b, ..., S) buffer
+# ----------------------------------------------------------------------
+def write_rows(buf, layer, rows, start):
+    """Write ``rows`` (b, s, ...) into layer ``layer`` of the stacked cache
+    ``buf`` from row ``start``; returns the buffer and that layer's rows as
+    (b, S, ...), the new ones included.
+
+    ``buf`` keeps the sequence axis last, (L, b, ..., S), and is held in
+    row-major layout, the one a TPU gives such an array when S is a
+    multiple of 128: the layer scan that carries it then writes the ``s``
+    rows in place. Left to choose, XLA gives the loop a row-contiguous
+    layout of its own and copies the whole buffer into and out of the loop
+    on every step."""
+    rows = jnp.moveaxis(rows, 1, -1)[None].astype(buf.dtype)
+    idx = (layer,) + (0,) * (buf.ndim - 2) + (start,)
+    buf = jax.lax.dynamic_update_slice(buf, rows, idx)
+    buf = with_layout_constraint(buf, Layout(tuple(range(buf.ndim))))
+    mine = jax.lax.dynamic_index_in_dim(buf, layer, keepdims=False)
+    return buf, jnp.moveaxis(mine, -1, 1)
+
+
+# ----------------------------------------------------------------------
 # GQA block
 # ----------------------------------------------------------------------
 def gqa_apply(p, x, cfg, kind: str = "causal", positions=None, cache=None,
-              pos=None, rule=None, window: int = 0, use_rope: bool = True):
-    """Returns (delta_x, new_cache). cache: dict(k, v, len) or None."""
+              pos=None, layer=None, rule=None, window: int = 0,
+              use_rope: bool = True):
+    """Returns (delta_x, cache). ``cache``: the stacked ``k``, ``v``
+    (L, b, kv, hd, S) of every layer, and for a local-window ring buffer
+    this layer's ``pos`` (S,); the block writes this ``layer``'s new rows
+    and returns the same entries, written."""
     b, s, d = x.shape
     xn = (rms_norm(x, p["ln"]) if cfg.norm == "rmsnorm"
           else layer_norm(x, p["ln"], p["ln_b"]))
@@ -186,34 +213,30 @@ def gqa_apply(p, x, cfg, kind: str = "causal", positions=None, cache=None,
     q_pos = positions[0] if positions.ndim == 2 else positions
     if cache is not None:
         with jax.named_scope("kv_update"):
-            ck, cv = cache["k"], cache["v"]
-            W = ck.shape[1]
+            W = cache["k"].shape[-1]
             if "pos" in cache:
                 # ring buffer (local-window layers): slot = position mod W
-                cp = cache["pos"]
                 if s >= W:   # prefill longer than the window: keep the tail
-                    ck = k[:, -W:].astype(ck.dtype)
-                    cv = v[:, -W:].astype(cv.dtype)
-                    cp = q_pos[-W:]
-                    cache = {"k": ck, "v": cv, "pos": cp}
+                    shift = q_pos[-W] % W       # the tail, in ring order
+                    ck, _ = write_rows(cache["k"], layer,
+                                       jnp.roll(k[:, -W:], shift, 1), 0)
+                    cv, _ = write_rows(cache["v"], layer,
+                                       jnp.roll(v[:, -W:], shift, 1), 0)
+                    cp = jnp.roll(q_pos[-W:], shift)
                     # attention itself sees the FULL in-call k/v (early queries
                     # need their own chunk, which the ring has already evicted)
                     kv_pos = q_pos
                 else:        # decode / short prefill (no intra-call wrap)
-                    slot = (pos if s == 1 else pos) % W
-                    ck = jax.lax.dynamic_update_slice(
-                        ck, k.astype(ck.dtype), (0, slot, 0, 0))
-                    cv = jax.lax.dynamic_update_slice(
-                        cv, v.astype(cv.dtype), (0, slot, 0, 0))
-                    cp = jax.lax.dynamic_update_slice(cp, q_pos, (slot,))
-                    cache = {"k": ck, "v": cv, "pos": cp}
-                    k, v, kv_pos = ck, cv, cp
+                    slot = pos % W
+                    ck, k = write_rows(cache["k"], layer, k, slot)
+                    cv, v = write_rows(cache["v"], layer, v, slot)
+                    cp = jax.lax.dynamic_update_slice(cache["pos"], q_pos,
+                                                      (slot,))
+                    kv_pos = cp
+                cache = {"k": ck, "v": cv, "pos": cp}
             else:
-                ck = jax.lax.dynamic_update_slice(
-                    ck, k.astype(ck.dtype), (0, pos, 0, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    cv, v.astype(cv.dtype), (0, pos, 0, 0))
-                k, v = ck, cv
+                ck, k = write_rows(cache["k"], layer, k, pos)
+                cv, v = write_rows(cache["v"], layer, v, pos)
                 kv_len = pos + s
                 cache = {"k": ck, "v": cv}
     o = attend(q, k, v, kind, q_pos=q_pos, kv_pos=kv_pos, window=window,
@@ -228,7 +251,11 @@ def gqa_apply(p, x, cfg, kind: str = "causal", positions=None, cache=None,
 # MLA block (DeepSeek-V3). Cache stores the compressed latent + rope key:
 # the paper's KV-cache reduction; K/V are re-expanded from the latent.
 # ----------------------------------------------------------------------
-def mla_apply(p, x, cfg, positions=None, cache=None, pos=None, rule=None):
+def mla_apply(p, x, cfg, positions=None, cache=None, pos=None, layer=None,
+              rule=None):
+    """Returns (delta_x, cache). ``cache``: the stacked ``latent`` and
+    ``k_rope`` of every layer; the block writes this ``layer``'s new rows
+    and returns both, written."""
     m = cfg.mla
     b, s, d = x.shape
     xn = rms_norm(x, p["ln"])
@@ -248,13 +275,8 @@ def mla_apply(p, x, cfg, positions=None, cache=None, pos=None, rule=None):
     kv_len, q_start = None, 0
     if cache is not None:
         with jax.named_scope("kv_update"):
-            cl = jax.lax.dynamic_update_slice(
-                cache["latent"], latent.astype(cache["latent"].dtype),
-                (0, pos, 0))
-            cr = jax.lax.dynamic_update_slice(
-                cache["k_rope"], k_rope.astype(cache["k_rope"].dtype),
-                (0, pos, 0, 0))
-        latent, k_rope = cl, cr
+            cl, latent = write_rows(cache["latent"], layer, latent, pos)
+            cr, k_rope = write_rows(cache["k_rope"], layer, k_rope, pos)
         cache = {"latent": cl, "k_rope": cr}
         kv_len, q_start = pos + s, pos
 

@@ -10,6 +10,12 @@ Zamba2's shared attention block) are expressed as group structure.
 Block kinds: attn (causal|local|nope|bidir), mla, mlp, moe, mamba,
 shared_attn (weight-tied across applications, per-application KV cache),
 cross (encoder-decoder).
+
+Serving caches mirror the stages. The sequence caches (K/V, MLA latent and
+rope key) are stacked over a stage's layers and ride in the scan's carry:
+each layer writes only its new rows at its own layer index, so a donated
+cache is updated in place. Small per-layer state (ring positions, SSM
+state, encoder K/V) goes through the scan's xs/ys and is replaced whole.
 """
 from __future__ import annotations
 
@@ -84,6 +90,31 @@ def build_stages(cfg) -> list[Stage]:
     stages.append(Stage((Block("mla" if cfg.mla else "attn"), ffn(0) if not cfg.moe
                          else Block("moe")), cfg.n_layers - cfg.n_dense_layers))
     return stages
+
+
+# ----------------------------------------------------------------------
+# Sequence caches: one row per position, per layer
+# ----------------------------------------------------------------------
+# Logical axes of one layer's sequence cache (one row per position), by
+# entry name, sequence axis last (attention.write_rows); cache_recs stacks
+# them over the layers. Every other cache entry is small per-layer state.
+_SEQ_AXES = {"k": ("batch", "act_kv", None, "kv_seq"),
+             "v": ("batch", "act_kv", None, "kv_seq"),
+             "latent": ("batch", None, "kv_seq"),
+             "k_rope": ("batch", None, None, "kv_seq")}
+
+
+def _seq_axes(name: str, ring: bool = False) -> tuple:
+    """Axes of sequence cache ``name``; a ring buffer (local-window layer)
+    keeps kv_seq local to the window."""
+    axes = _SEQ_AXES[name]
+    return tuple(None if ring and a == "kv_seq" else a for a in axes)
+
+
+def _split_cache(cache: dict) -> tuple[dict, dict]:
+    """(sequence entries, small per-layer state) of one block's cache."""
+    return ({k: v for k, v in cache.items() if k in _SEQ_AXES},
+            {k: v for k, v in cache.items() if k not in _SEQ_AXES})
 
 
 # ----------------------------------------------------------------------
@@ -179,23 +210,21 @@ class LM:
                 local = (blk.opts.get("kind") == "local"
                          and cfg.local_window < max_len)
                 s = cfg.local_window if local else max_len
-                kv_axes = ("batch", "kv_seq", "act_kv", None)
                 out = {}
                 if local:
-                    # ring buffer: kv_seq stays local to the window
-                    kv_axes = ("batch", None, "act_kv", None)
                     out["pos"] = PRec((s,), (None,), dtype=jnp.int32,
                                       init="fill", scale=-1)
-                out["k"] = PRec((batch, s, kvh, hd), kv_axes, init="zeros")
-                out["v"] = PRec((batch, s, kvh, hd), kv_axes, init="zeros")
+                for name in ("k", "v"):
+                    out[name] = PRec((batch, kvh, hd, s),
+                                     _seq_axes(name, ring=local),
+                                     init="zeros")
                 return out
             if blk.kind == "mla":
                 m = cfg.mla
-                return {"latent": PRec((batch, max_len, m.kv_lora),
-                                       ("batch", "kv_seq", None), init="zeros"),
-                        "k_rope": PRec((batch, max_len, 1, m.qk_rope_dim),
-                                       ("batch", "kv_seq", None, None),
-                                       init="zeros")}
+                return {"latent": PRec((batch, m.kv_lora, max_len),
+                                       _seq_axes("latent"), init="zeros"),
+                        "k_rope": PRec((batch, 1, m.qk_rope_dim, max_len),
+                                       _seq_axes("k_rope"), init="zeros")}
             if blk.kind == "mamba":
                 shapes = mamba2.mamba2_cache_shape(cfg, batch)
                 return {"ssm": PRec(shapes["ssm"][0],
@@ -219,7 +248,7 @@ class LM:
 
     # -- forward ----------------------------------------------------------
     def _apply_block(self, blk: Block, p, x, rule, cache=None, pos=None,
-                     shared=None, enc_out=None, x_emb=None):
+                     layer=None, shared=None, enc_out=None, x_emb=None):
         cfg = self.cfg
         if blk.kind == "attn":
             kind = blk.opts.get("kind", "causal")
@@ -228,19 +257,20 @@ class LM:
             dx, c = attention.gqa_apply(
                 p, x, cfg, kind="local" if kind == "local" else
                 ("causal" if kind != "bidir" else "bidir"),
-                cache=cache, pos=pos, rule=rule, window=window,
+                cache=cache, pos=pos, layer=layer, rule=rule, window=window,
                 use_rope=use_rope)
             return x + dx, c
         if blk.kind == "shared_attn":
             xin = jnp.einsum("bse,ed->bsd",
                              jnp.concatenate([x, x_emb], -1), shared["w_concat"])
             dx, c = attention.gqa_apply(shared, xin, cfg, kind="causal",
-                                        cache=cache, pos=pos, rule=rule)
+                                        cache=cache, pos=pos, layer=layer,
+                                        rule=rule)
             x = x + dx
             return x + mlp.mlp_apply(shared["mlp"], x, cfg, rule=rule), c
         if blk.kind == "mla":
             dx, c = attention.mla_apply(p, x, cfg, cache=cache, pos=pos,
-                                        rule=rule)
+                                        layer=layer, rule=rule)
             return x + dx, c
         if blk.kind == "mlp":
             return x + mlp.mlp_apply(p, x, cfg, rule=rule), cache
@@ -264,30 +294,53 @@ class LM:
 
     def _run_stages(self, params, x, rule, caches=None, pos=None,
                     enc_out=None, x_emb=None, remat=False):
-        cfg = self.cfg
+        """Scan each stage's layers. With ``caches``, the sequence caches
+        ride in the scan's carry as stacked ``(L, b, ..., S)`` buffers:
+        layer ``i`` writes its new rows at index ``i``, row ``pos``, and
+        reads its rows back from the same buffer, so a donated cache is
+        updated in place. The small per-layer state goes through the
+        scan's ``xs``/``ys``, replaced whole."""
         new_caches = []
         for si, st in enumerate(self.stages):
             pstack = params["stages"][si]["blocks"]
-            cstack = caches[si]["blocks"] if caches is not None else None
+            if caches is None:
+                seq, small = [{} for _ in st.blocks], None
+            else:
+                seq, small = map(list, zip(*map(_split_cache,
+                                                caches[si]["blocks"])))
+            axes = [{k: ("layers",) + _seq_axes(k, ring="pos" in sm)
+                     for k in sq}
+                    for sq, sm in zip(seq, small or [{}] * len(seq))]
 
-            def body(xc, layer_in, _st=st, _ps=None):
-                lp, lc = layer_in
-                newc = []
+            def pin(seq, _axes=axes):
+                """Keep each carried buffer on its cache's sharding."""
+                if rule is None:
+                    return seq
+                return [{k: constrain(v, rule, ax[k]) for k, v in c.items()}
+                        for c, ax in zip(seq, _axes)]
+
+            def body(carry, layer_in, _st=st):
+                xc, seq = carry
+                lp, lsmall, li = layer_in
+                seq, new_small = list(seq), []
                 for bi, blk in enumerate(_st.blocks):
-                    bc = lc[bi] if lc is not None else None
+                    bc = None if lsmall is None else {**seq[bi],
+                                                      **lsmall[bi]}
                     with jax.named_scope(blk.kind):
                         xc, bc = self._apply_block(
                             blk, lp[bi], xc, rule, cache=bc, pos=pos,
-                            shared=params.get("shared"), enc_out=enc_out,
-                            x_emb=x_emb)
-                    newc.append(bc if bc is not None else {})
-                return xc, newc
+                            layer=li, shared=params.get("shared"),
+                            enc_out=enc_out, x_emb=x_emb)
+                    seq[bi], sm = _split_cache(bc or {})
+                    new_small.append(sm)
+                return (xc, pin(seq)), new_small
 
             body_fn = jax.checkpoint(body) if remat else body
-            x, outc = jax.lax.scan(
-                lambda carry, xs: body_fn(carry, xs),
-                x, (pstack, cstack))
-            new_caches.append({"blocks": outc})
+            (x, seq), small = jax.lax.scan(
+                body_fn, (x, pin(seq)),
+                (pstack, small, jnp.arange(st.repeat, dtype=jnp.int32)))
+            new_caches.append({"blocks": [{**a, **b}
+                                          for a, b in zip(seq, small)]})
         return x, (new_caches if caches is not None else None)
 
     def _embed(self, params, tokens, batch_extra, rule):

@@ -48,7 +48,10 @@ class Engine:
     With a ``mesh`` (and the sharding ``rule`` the model's constraints
     use), params and caches are placed by the rule's ``PartitionSpec``s
     and every step runs under that mesh. Caches are donated to the step
-    that updates them, so one copy of the KV cache is live at a time."""
+    that updates them, and the step writes only each layer's new rows into
+    them (``LM._run_stages``): one copy of the KV cache is live, and the
+    compiled step holds no second one, neither as scratch nor as a copy
+    into or out of the layer loop."""
 
     def __init__(self, model, params, max_len: int, rule=None, mesh=None):
         self.model, self.max_len = model, max_len

@@ -4,6 +4,7 @@ output shapes and finiteness; prefill+decode must agree with the full
 forward (the KV-cache/ring-buffer/SSM-state correctness proof)."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro import configs
@@ -98,3 +99,86 @@ def test_local_window_ring_buffer():
     err = jnp.max(jnp.abs(lg[:, 0].astype(jnp.float32)
                           - full[:, -1].astype(jnp.float32)))
     assert float(err) < 0.05
+
+
+# Cache entries with one row per position, sequence axis last; the rest
+# (ring positions, SSM state, encoder K/V) is small per-layer state.
+_SEQ_ENTRIES = ("k", "v", "latent", "k_rope")
+
+
+def _bits(a):
+    return np.ascontiguousarray(a).view(np.uint8)
+
+
+@pytest.mark.parametrize("arch", configs.ARCH_IDS)
+def test_donated_decode_rewrites_only_its_row(arch):
+    """A jitted decode step that donates its caches writes only the row it
+    decodes: every other row of each sequence cache, and every other slot
+    of a ring buffer's positions, comes back bit-identical; encoder K/V
+    come back whole. The caches start as noise, so a copy that moved or
+    zeroed a row would show."""
+    cfg = configs.reduced(configs.get_config(arch))
+    model = LM(cfg)
+    params = materialize(model.param_recs(), jax.random.PRNGKey(0))
+    b, max_len, pos = 2, 96, 80      # local layers: ring of 64, slot 16
+    leaves, tree = jax.tree_util.tree_flatten_with_path(
+        make_caches(model, b, max_len))
+    noise = [jnp.arange(x.size, dtype=x.dtype).reshape(x.shape)
+             if x.dtype == jnp.int32 else
+             jax.random.normal(jax.random.fold_in(jax.random.PRNGKey(2), i),
+                               x.shape).astype(x.dtype)
+             for i, (_, x) in enumerate(leaves)]
+    before = [np.asarray(x) for x in noise]
+    step = jax.jit(model.decode_step, donate_argnums=1)
+    _, out = step(params, jax.tree_util.tree_unflatten(tree, noise),
+                  jnp.ones((b, 1), jnp.int32), jnp.int32(pos))
+    after, out_tree = jax.tree_util.tree_flatten(out)
+    assert out_tree == tree
+    for (path, _), old, new in zip(leaves, before, after):
+        new = np.asarray(new)
+        assert new.shape == old.shape and new.dtype == old.dtype, path
+        name = path[-1].key
+        if name in _SEQ_ENTRIES or name == "pos":
+            row = pos % old.shape[-1]
+            keep = np.arange(old.shape[-1]) != row
+            assert (_bits(new[..., keep]) == _bits(old[..., keep])).all(), \
+                f"{arch}: {jax.tree_util.keystr(path)} rows moved"
+            assert (_bits(new[..., row]) != _bits(old[..., row])).any(), \
+                f"{arch}: {jax.tree_util.keystr(path)} row {row} unwritten"
+            if name == "pos":
+                assert (new[..., row] == pos).all()
+        elif name in ("ck", "cv"):
+            assert (_bits(new) == _bits(old)).all(), path
+
+
+@pytest.mark.parametrize("arch", configs.ARCH_IDS)
+def test_decode_to_last_row_matches_forward(arch):
+    """Prefill 112 tokens (past a local layer's 64-token window, so its
+    ring keeps the tail), then decode one token at a time to the cache's
+    last row (the ring wraps and crosses a chunk boundary): every step's
+    logits match the full forward at that position."""
+    cfg = configs.reduced(configs.get_config(arch))
+    model = LM(cfg)
+    params = materialize(model.param_recs(), jax.random.PRNGKey(0))
+    b, s0, max_len = 2, 112, 160   # s0: a whole number of SSD chunks
+    batch = _batch(cfg, b, max_len, jax.random.PRNGKey(1))
+    toks = batch["tokens"]
+    full = jax.jit(model.forward)(params, batch).astype(jnp.float32)
+    prefill = jax.jit(model.prefill, donate_argnums=2)
+    decode = jax.jit(model.decode_step, donate_argnums=1)
+    _, caches = prefill(params, dict(batch, tokens=toks[:, :s0]),
+                        make_caches(model, b, max_len))
+    errs, agree = [], []
+    for i in range(s0, max_len):
+        lg, caches = decode(params, caches, toks[:, i:i + 1], jnp.int32(i))
+        lg = lg[:, 0].astype(jnp.float32)
+        errs.append(float(jnp.max(jnp.abs(lg - full[:, i]))))
+        agree.append(bool(jnp.all(jnp.argmax(lg, -1)
+                                  == jnp.argmax(full[:, i], -1))))
+    # 48 steps drift more than the two of test_prefill_decode_matches_forward
+    # (zamba2's recurrent SSM against the chunked scan): allow four bf16
+    # rounding steps at the largest logit, half what the serving replay
+    # check allows (2**-4 of it)
+    tol = 2 ** -5 * float(jnp.max(jnp.abs(full[:, s0:])))
+    assert max(errs) < tol, f"{arch}: decode/forward logit gap {max(errs)}"
+    assert agree[-1], f"{arch}: argmax mismatch at the last row"

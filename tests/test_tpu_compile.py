@@ -3,22 +3,29 @@
 attention at phi3-mini's head shape), with ``interpret=False`` passed
 explicitly. The TPU compiler runs here against a described, unattached
 chip, so it refuses what the chip would refuse: unsupported lowerings,
-and more VMEM than the chip has.
+and more VMEM than the chip has. Also the served decode step at phi3-mini
+widths, whose compiled program must update the donated KV cache in place.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports every
 test file. All such compiles stay in this one file for the same reason.
 """
+import dataclasses
 import functools
 import importlib
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import configs
 from repro.kernels import ops
+from repro.models.common import abstract_tree
+from repro.models.lm import LM
 
 _lr = importlib.import_module("repro.kernels.longrange3d")
 _s7 = importlib.import_module("repro.kernels.stencil3d7pt")
@@ -112,3 +119,48 @@ def test_vmem_count_admits_paper_sizes():
     assert _s7.vmem_bytes(N, 4) < vmem / 2
     assert _lr.vmem_bytes(N, 4) < _lr.vmem_bytes(1024, 4) < vmem
     assert _lr.vmem_bytes(1040, 4) > vmem
+
+
+# one instruction of compiled HLO text: name, result type(s), opcode
+_INSTR = re.compile(r"%(?P<name>[\w.\-]+) = (?P<type>.+?) (?P<op>[\w\-]+)\(")
+
+
+def _largest_result(typ: str) -> int:
+    """Elements of the largest array among an instruction's results."""
+    return max((math.prod(int(d) for d in dims.split(",") if d)
+                for dims in re.findall(r"\w+\[([\d,]*)\]", typ)),
+               default=0)
+
+
+def test_decode_step_updates_kv_cache_in_place(one_chip):
+    """phi3-mini's decode step, cut to 2 layers, batch 4, 2048 cache rows,
+    bf16, caches donated: the compiled program writes each layer's new row
+    into the stacked K/V and moves no cache. No copy as large as one
+    layer's K, no dynamic-update-slice fusion that rewrites a whole stacked
+    buffer, and scratch memory below one stacked K."""
+    layers, batch, rows = 2, 4, 2048
+    cfg = dataclasses.replace(configs.get_config("phi3-mini-3.8b"),
+                              n_layers=layers, tp=1)
+    model = LM(cfg)
+
+    def spec(tree):
+        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                            abstract_tree(tree, default_dtype=jnp.bfloat16))
+
+    caches = spec(model.cache_recs(batch, rows))
+    compiled = jax.jit(model.decode_step, donate_argnums=1).lower(
+        spec(model.param_recs()), caches,
+        _spec((batch, 1), jnp.int32, one_chip),
+        _spec((), jnp.int32, one_chip)).compile()
+
+    k_layer = batch * cfg.n_kv_heads * cfg.head_dim * rows
+    stacked_k_bytes = layers * k_layer * 2
+    assert {x.size for x in jax.tree.leaves(caches)} == {layers * k_layer}
+    for m in _INSTR.finditer(compiled.as_text()):
+        size = _largest_result(m["type"])
+        if m["op"] in ("copy", "copy-start"):
+            assert size < k_layer, f"cache copy: {m[0]}"
+        if m["op"] == "fusion" and "dynamic-update-slice" in m["name"]:
+            assert size < layers * k_layer, f"whole-cache write: {m[0]}"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < stacked_k_bytes, (temp, stacked_k_bytes)
