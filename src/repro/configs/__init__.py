@@ -14,6 +14,7 @@ import importlib
 import jax
 import jax.numpy as jnp
 
+from repro.models.common import YarnConfig
 from repro.models.mamba2 import SSMConfig
 from repro.models.moe import MoEConfig
 
@@ -61,6 +62,8 @@ class ArchConfig:
     act_dtype: str = "bfloat16"    # activation/KV-cache dtype
     n_img_tokens: int = 0          # pixtral: stubbed patch-embedding count
     zero_inference: bool = False   # shard weights over `data` when serving
+    tie_embed: bool = True         # False: an output head of its own
+    rope_scaling: YarnConfig | None = None   # deepseek: YaRN on rope dims
     source: str = ""
 
     def __post_init__(self):

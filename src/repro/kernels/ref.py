@@ -61,3 +61,20 @@ def attention(q, k, v, causal: bool = True):
     p = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p,
                       v.astype(jnp.float32)).astype(q.dtype)
+
+
+# ----------------------------------------------------------------------
+# grouped matmul over a MoE layer's held experts (kernels/moe_gmm.py)
+# ----------------------------------------------------------------------
+def grouped_matmul(x, w, group_sizes):
+    """x: (P, d) rows sorted by group; w: (E, d, f); group_sizes: (E,).
+    Row ``p`` of group ``g`` -> ``x[p] @ w[g]`` in float32; rows past the
+    last group -> 0. Returns (P, f) in ``x``'s dtype."""
+    ends = jnp.cumsum(group_sizes)
+    g = jnp.searchsorted(ends, jnp.arange(x.shape[0]), side="right")
+    out = jnp.zeros((x.shape[0], w.shape[-1]), jnp.float32)
+    for e in range(w.shape[0]):
+        y = jnp.dot(x.astype(jnp.float32), w[e].astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST)
+        out = jnp.where((g == e)[:, None], y, out)
+    return out.astype(x.dtype)

@@ -180,7 +180,9 @@ def main(argv=None):
           f"{len(res.done)} requests, {res.tokens} tokens in "
           f"{res.seconds:.2f}s ({res.tokens / res.seconds:.1f} tok/s, "
           f"compiles included), batches={res.batches}")
-    print(f"  counts: {dataclasses.asdict(res.counts)}")
+    counts = {k: v for k, v in dataclasses.asdict(res.counts).items()
+              if v != {}}              # the MoE counters of MoE models only
+    print(f"  counts: {counts}")
     print("  phases: " + ", ".join(f"{k} {v:.3f}s" for k, v in
                                    phase_seconds(t0).items()))
     for r in res.done[:3]:

@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from .common import PRec, constrain, layer_norm, pad_heads, rms_norm, rope
+from .common import (PRec, constrain, layer_norm, pad_heads, rms_norm, rope,
+                     yarn_mscale)
 
 NEG_INF = -2.0 ** 30  # large-but-finite: keeps fully-masked rows NaN-free
 
@@ -251,11 +252,61 @@ def gqa_apply(p, x, cfg, kind: str = "causal", positions=None, cache=None,
 # MLA block (DeepSeek-V3). Cache stores the compressed latent + rope key:
 # the paper's KV-cache reduction; K/V are re-expanded from the latent.
 # ----------------------------------------------------------------------
+def mla_scale(cfg) -> float:
+    """MLA's softmax scale: (nope + rope dims)^-1/2, times YaRN's
+    ``mscale_all_dim`` factor squared when the config scales rope."""
+    m, y = cfg.mla, cfg.rope_scaling
+    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+#: MLA prefill scores this many bytes of float32 scores at a time at most
+MLA_SCORE_BYTES = 1 << 29
+
+
+def _mla_expanded(q_nope, q_rope, k_nope, k_rope, vv, mask, scale):
+    scores = (jnp.einsum("bqnh,bknh->bnqk", q_nope, k_nope)
+              + jnp.einsum("bqnh,bkoh->bnqk", q_rope,
+                           jnp.broadcast_to(k_rope, k_rope.shape))) \
+        * scale
+    pr = _softmax(scores, mask)
+    return jnp.einsum("bnqk,bknh->bqnh", pr.astype(vv.dtype), vv)
+
+
+def _mla_query_chunks(q_nope, q_rope, k_nope, k_rope, vv, mask, scale):
+    """The expanded form a chunk of queries at a time, so that no more
+    than :data:`MLA_SCORE_BYTES` of scores is live: (b, s, h, v_dim)."""
+    b, s, h, _ = q_nope.shape
+    skv = k_nope.shape[1]
+    cq = s
+    while cq > 8 and (b * h * cq * skv * 4 > MLA_SCORE_BYTES or s % cq):
+        cq //= 2
+    if s % cq or cq == s:
+        return _mla_expanded(q_nope, q_rope, k_nope, k_rope, vv, mask,
+                             scale)
+    n = s // cq
+
+    def chunks(a):
+        return a.reshape(a.shape[0], n, cq, *a.shape[2:]).swapaxes(0, 1)
+
+    def body(_, args):
+        qn, qr, mk = args
+        return None, _mla_expanded(qn, qr, k_nope, k_rope, vv, mk, scale)
+
+    _, o = jax.lax.scan(body, None, (chunks(q_nope), chunks(q_rope),
+                                     mask.reshape(n, cq, skv)))
+    return o.swapaxes(0, 1).reshape(b, s, h, vv.shape[-1])
+
+
 def mla_apply(p, x, cfg, positions=None, cache=None, pos=None, layer=None,
               rule=None):
     """Returns (delta_x, cache). ``cache``: the stacked ``latent`` and
     ``k_rope`` of every layer; the block writes this ``layer``'s new rows
-    and returns both, written."""
+    and returns both, written. A prefill (cache, several tokens) scores a
+    chunk of queries at a time; a decode step scores the latent rows
+    directly (weight absorption)."""
     m = cfg.mla
     b, s, d = x.shape
     xn = rms_norm(x, p["ln"])
@@ -269,8 +320,9 @@ def mla_apply(p, x, cfg, positions=None, cache=None, pos=None, layer=None,
     latent = rms_norm(latent, p["kv_ln"])
     if positions is None:
         positions = jnp.arange(s)[None, :] + (0 if pos is None else pos)
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
-    k_rope = rope(k_rope[..., None, :], positions, cfg.rope_theta)
+    q_rope = rope(q_rope, positions, cfg.rope_theta, yarn=cfg.rope_scaling)
+    k_rope = rope(k_rope[..., None, :], positions, cfg.rope_theta,
+                  yarn=cfg.rope_scaling)
 
     kv_len, q_start = None, 0
     if cache is not None:
@@ -279,8 +331,10 @@ def mla_apply(p, x, cfg, positions=None, cache=None, pos=None, layer=None,
             cr, k_rope = write_rows(cache["k_rope"], layer, k_rope, pos)
         cache = {"latent": cl, "k_rope": cr}
         kv_len, q_start = pos + s, pos
+        if s > 1 and isinstance(pos, int):   # prefill: the rows so far
+            latent, k_rope = latent[:, :kv_len], k_rope[:, :kv_len]
 
-    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scale = mla_scale(cfg)
     skv = latent.shape[1]
     mask = _causal_mask(s, skv, q_start)
     if kv_len is not None:
@@ -294,18 +348,19 @@ def mla_apply(p, x, cfg, positions=None, cache=None, pos=None, layer=None,
         # (§Perf: 260x less decode MXU work at skv=32k).
         # fp32 through the (tiny) absorbed q/o tensors: the extra rounding
         # of the two-hop latent contraction otherwise drifts logits
-        q_lat = jnp.einsum("bqnh,rnh->bqnr", q_nope, p["wk_b"],
-                           preferred_element_type=jnp.float32)
-        scores = (jnp.einsum("bqnr,bkr->bnqk", q_lat,
-                             latent.astype(jnp.float32))
-                  + jnp.einsum("bqnh,bkoh->bnqk", q_rope,
-                               jnp.broadcast_to(k_rope, k_rope.shape))) \
-            * scale
-        pr = _softmax(scores, mask)
-        o_lat = jnp.einsum("bnqk,bkr->bqnr", pr,
-                           latent.astype(jnp.float32))
-        o = jnp.einsum("bqnr,rnh->bqnh", o_lat,
-                       p["wv_b"].astype(jnp.float32)).astype(x.dtype)
+        with jax.named_scope("mla_decode"):
+            q_lat = jnp.einsum("bqnh,rnh->bqnr", q_nope, p["wk_b"],
+                               preferred_element_type=jnp.float32)
+            scores = (jnp.einsum("bqnr,bkr->bnqk", q_lat,
+                                 latent.astype(jnp.float32))
+                      + jnp.einsum("bqnh,bkoh->bnqk", q_rope,
+                                   jnp.broadcast_to(k_rope, k_rope.shape))) \
+                * scale
+            pr = _softmax(scores, mask)
+            o_lat = jnp.einsum("bnqk,bkr->bqnr", pr,
+                               latent.astype(jnp.float32))
+            o = jnp.einsum("bqnr,rnh->bqnh", o_lat,
+                           p["wv_b"].astype(jnp.float32)).astype(x.dtype)
     else:
         # TRAIN/PREFILL: expand keys/values from the latent (per-head)
         k_nope = jnp.einsum("bsr,rnh->bsnh", latent, p["wk_b"])
@@ -316,20 +371,21 @@ def mla_apply(p, x, cfg, positions=None, cache=None, pos=None, layer=None,
             k_nope = constrain(k_nope, rule,
                                ("batch", None, "act_heads", None))
             vv = constrain(vv, rule, ("batch", None, "act_heads", None))
-        # NB: q-chunking this path was tried and REFUTED (§Perf r5): with
-        # seq-sharded q the per-chunk reshard triggers involuntary full
-        # rematerialization in the SPMD partitioner (23.5 TiB of extra
-        # all-gathers). The fp32 score-tile traffic is instead addressed by
-        # the Pallas flash kernel on real TPUs (kernel-aware §Roofline).
-        # Exact prefill/decode logit parity (same-argmax tests) comes from
-        # cfg.act_dtype=float32, not from forcing fp32 here — bf16 configs
-        # keep bf16 score/value tiles.
-        scores = (jnp.einsum("bqnh,bknh->bnqk", q_nope, k_nope)
-                  + jnp.einsum("bqnh,bkoh->bnqk", q_rope,
-                               jnp.broadcast_to(k_rope, k_rope.shape))) \
-            * scale
-        pr = _softmax(scores, mask)
-        o = jnp.einsum("bnqk,bknh->bqnh", pr.astype(vv.dtype), vv)
+        if cache is not None and rule is None:
+            o = _mla_query_chunks(q_nope, q_rope, k_nope, k_rope, vv, mask,
+                                  scale)
+        else:
+            # NB: q-chunking the sharded path was tried and REFUTED (§Perf
+            # r5): with seq-sharded q the per-chunk reshard triggers
+            # involuntary full rematerialization in the SPMD partitioner
+            # (23.5 TiB of extra all-gathers). The fp32 score-tile traffic
+            # is instead addressed by the Pallas flash kernel on real TPUs
+            # (kernel-aware §Roofline). Exact prefill/decode logit parity
+            # (same-argmax tests) comes from cfg.act_dtype=float32, not
+            # from forcing fp32 here — bf16 configs keep bf16 score/value
+            # tiles.
+            o = _mla_expanded(q_nope, q_rope, k_nope, k_rope, vv, mask,
+                              scale)
     out = jnp.einsum("bsnh,nhd->bsd", o, p["wo"])
     if rule is not None:
         out = constrain(out, rule, ("batch", "seq", "act_embed"))

@@ -12,6 +12,7 @@ The rule tables implement DP/FSDP/TP/EP/SP as *roles* of the two mesh axes
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -139,11 +140,16 @@ def layer_norm(x, gamma, beta, eps: float = 1e-5):
     return ((xf - mu) * jax.lax.rsqrt(var + eps) * gamma + beta).astype(x.dtype)
 
 
-def rope(x, positions, theta: float = 10000.0, scale: float = 1.0):
-    """Rotary embedding over the last dim of x: (..., seq, heads, hd)."""
+def rope(x, positions, theta: float = 10000.0, scale: float = 1.0,
+         yarn: YarnConfig | None = None):
+    """Rotary embedding over the last dim of x: (..., seq, heads, hd);
+    with ``yarn``, YaRN's frequencies."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = (theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)) * scale
+    if yarn is None:
+        freqs = (theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)) * scale
+    else:
+        freqs = yarn_inv_freq(hd, theta, yarn)
     # positions: (..., seq) -> angles (..., seq, 1, half)
     ang = positions.astype(jnp.float32)[..., None, None] * freqs
     cos, sin = jnp.cos(ang), jnp.sin(ang)
@@ -151,6 +157,40 @@ def rope(x, positions, theta: float = 10000.0, scale: float = 1.0):
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     return jnp.concatenate([xf1 * cos - xf2 * sin,
                             xf2 * cos + xf1 * sin], axis=-1).astype(x.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (arXiv:2309.00071), as DeepSeek-V3's config
+    ``rope_scaling`` states it. Its ``mscale`` equals ``mscale_all_dim``,
+    so cos and sin stay unscaled and only the softmax scale carries
+    ``mscale_all_dim``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 1.0
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, y: YarnConfig):
+    """(dim // 2,) frequencies: the extrapolated ``theta^(-2i/dim)`` below
+    the correction range, those divided by ``factor`` above it, and a
+    linear ramp between, the range's ends counted in dims from
+    ``beta_fast`` and ``beta_slow`` rotations at the original length."""
+    def dim_of(rot):
+        return (dim * math.log(y.original_max_position / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim_of(y.beta_fast)), 0)
+    high = min(math.ceil(dim_of(y.beta_slow)), dim - 1)
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    extra = theta ** (-2.0 * i / dim)
+    ramp = jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return extra * keep + extra / y.factor * (1.0 - keep)
 
 
 def pad_heads(n: int, tp: int = 16) -> int:

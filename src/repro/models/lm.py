@@ -111,6 +111,10 @@ def _seq_axes(name: str, ring: bool = False) -> tuple:
     return tuple(None if ring and a == "kv_seq" else a for a in axes)
 
 
+#: the named scope of a block kind in the trace, where it is not the kind
+_SCOPES = {"mla": "attn"}
+
+
 def _split_cache(cache: dict) -> tuple[dict, dict]:
     """(sequence entries, small per-layer state) of one block's cache."""
     return ({k: v for k, v in cache.items() if k in _SEQ_AXES},
@@ -152,8 +156,8 @@ class LM:
         cfg = self.cfg
         d = cfg.d_model
         recs: dict[str, Any] = {
-            # tied in/out embedding: d^-1/2 init keeps head logits O(1)
-            # (rmsnorm renormalizes the input side)
+            # in (and, when tied, out) embedding: d^-1/2 init keeps head
+            # logits O(1) (rmsnorm renormalizes the input side)
             "embed": PRec((self.padded_vocab, d), ("vocab", "embed"),
                           scale=d ** -0.5),
             "final_ln": PRec((d,), ("embed",),
@@ -161,6 +165,9 @@ class LM:
         }
         if cfg.norm == "layernorm":
             recs["final_ln_b"] = PRec((d,), ("embed",), init="zeros")
+        if not cfg.tie_embed:       # an output head of its own
+            recs["head"] = PRec((self.padded_vocab, d), ("vocab", "embed"),
+                                scale=d ** -0.5)
         stage_recs = []
         for st in self.stages:
             blocks = []
@@ -225,6 +232,11 @@ class LM:
                                        _seq_axes("latent"), init="zeros"),
                         "k_rope": PRec((batch, 1, m.qk_rope_dim, max_len),
                                        _seq_axes("k_rope"), init="zeros")}
+            if blk.kind == "moe" and cfg.moe.ep_size:
+                # the held experts' pairs and active experts, summed over
+                # the calls that wrote this cache (Engine reads them)
+                return {"moe_stats": PRec((2,), (None,), dtype=jnp.int32,
+                                          init="zeros")}
             if blk.kind == "mamba":
                 shapes = mamba2.mamba2_cache_shape(cfg, batch)
                 return {"ssm": PRec(shapes["ssm"][0],
@@ -274,6 +286,11 @@ class LM:
             return x + dx, c
         if blk.kind == "mlp":
             return x + mlp.mlp_apply(p, x, cfg, rule=rule), cache
+        if blk.kind == "moe" and cfg.moe.ep_size:
+            dx, stats = moe.held_apply(p, x, cfg, layer=layer)
+            if cache is not None:
+                cache = {"moe_stats": cache["moe_stats"] + stats}
+            return x + dx, cache
         if blk.kind == "moe":
             return x + moe.moe_apply(p, x, cfg, rule=rule), cache
         if blk.kind == "mamba":
@@ -303,6 +320,14 @@ class LM:
         new_caches = []
         for si, st in enumerate(self.stages):
             pstack = params["stages"][si]["blocks"]
+            # a held-expert layer's matrices stay stacked, outside the
+            # scan's xs: its kernel reads layer i where it lies, where a
+            # per-layer slice would be copied whole for the custom call
+            stacks = [{k: p[k] for k in moe.EXPERT_WEIGHTS}
+                      if blk.kind == "moe" and self.cfg.moe.ep_size else {}
+                      for blk, p in zip(st.blocks, pstack)]
+            pstack = [{k: v for k, v in p.items() if k not in w}
+                      for p, w in zip(pstack, stacks)]
             if caches is None:
                 seq, small = [{} for _ in st.blocks], None
             else:
@@ -319,16 +344,17 @@ class LM:
                 return [{k: constrain(v, rule, ax[k]) for k, v in c.items()}
                         for c, ax in zip(seq, _axes)]
 
-            def body(carry, layer_in, _st=st):
+            def body(carry, layer_in, _st=st, _stacks=stacks):
                 xc, seq = carry
                 lp, lsmall, li = layer_in
                 seq, new_small = list(seq), []
                 for bi, blk in enumerate(_st.blocks):
                     bc = None if lsmall is None else {**seq[bi],
                                                       **lsmall[bi]}
-                    with jax.named_scope(blk.kind):
+                    with jax.named_scope(_SCOPES.get(blk.kind, blk.kind)):
                         xc, bc = self._apply_block(
-                            blk, lp[bi], xc, rule, cache=bc, pos=pos,
+                            blk, {**lp[bi], **_stacks[bi]}, xc, rule,
+                            cache=bc, pos=pos,
                             layer=li, shared=params.get("shared"),
                             enc_out=enc_out, x_emb=x_emb)
                     seq[bi], sm = _split_cache(bc or {})
@@ -414,7 +440,9 @@ class LM:
             x = (rms_norm(x, params["final_ln"]) if cfg.norm == "rmsnorm"
                  else layer_norm(x, params["final_ln"],
                                  params["final_ln_b"]))
-            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+            logits = jnp.einsum("bsd,vd->bsv", x,
+                                params["head" if "head" in params
+                                       else "embed"])
             if self.padded_vocab != cfg.vocab:   # mask vocab-padding
                 pad_mask = jnp.arange(self.padded_vocab) >= cfg.vocab
                 logits = jnp.where(pad_mask, jnp.float32(

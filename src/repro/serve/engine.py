@@ -19,6 +19,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import obs
 from repro.models.common import materialize, shardings
@@ -69,6 +70,12 @@ class Engine:
 
         self.prefill = jax.jit(_prefill, donate_argnums=2)
         self.decode = jax.jit(_decode, donate_argnums=1)
+        #: (after prefill, after the last decode step) of the last
+        #: generate call: the held experts' routed pairs and active experts,
+        #: on the device; None for a model without a held-expert layer
+        self.moe_counts = None
+        self._moe_totals = (jax.jit(_moe_totals) if _has_moe_stats(
+            model.cache_recs(1, 1)) else None)
 
     def mesh_context(self):
         """The context the engine's steps run in: its mesh, if any."""
@@ -108,6 +115,8 @@ class Engine:
                 batch = {"tokens": tokens, **(extras or {})}
                 logits, caches = self.prefill(self.params, batch, caches)
                 tok = self._sample(logits, temperature, key)
+                if self._moe_totals is not None:
+                    after_prefill = self._moe_totals(caches)
             out = [tok]
             for i in range(1, n_new):
                 with obs.span("serve.decode_step", step=i):
@@ -116,7 +125,23 @@ class Engine:
                                                  jnp.int32(s0 + i - 1))
                     tok = self._sample(logits, temperature, sub)
                 out.append(tok)
+            if self._moe_totals is not None:
+                self.moe_counts = (after_prefill, self._moe_totals(caches))
         return out
+
+
+def _has_moe_stats(tree) -> bool:
+    return any(getattr(k, "key", None) == "moe_stats"
+               for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]
+               for k in path)
+
+
+def _moe_totals(caches):
+    """(2,) int32: every held-expert layer's ``moe_stats`` summed."""
+    leaves = [leaf for path, leaf in
+              jax.tree_util.tree_flatten_with_path(caches)[0]
+              if getattr(path[-1], "key", None) == "moe_stats"]
+    return sum(leaf.reshape(-1, 2).sum(0) for leaf in leaves)
 
 
 def left_pad(prompts: list[list[int]]):
@@ -134,6 +159,11 @@ class ServeCounts:
     prompt_tokens: int = 0      # the requests' own prompt tokens
     pad_tokens: int = 0         # left padding up to each batch's longest
     new_tokens: int = 0         # tokens the engine made, rows x steps
+    #: held-expert layers only, by call kind ("prefill", "decode"): routed
+    #: (token, expert) pairs the held experts computed, and held experts
+    #: that got at least one pair, summed over layers and calls
+    moe_pairs: dict = dataclasses.field(default_factory=dict)
+    moe_active: dict = dataclasses.field(default_factory=dict)
 
 
 class BatchedServer:
@@ -187,6 +217,9 @@ class BatchedServer:
                     gen = jnp.concatenate(steps, axis=1)
                     for i, r in enumerate(bucket):
                         r.result = [int(t) for t in gen[i][:r.max_new]]
+                    if self.engine.moe_counts is not None:
+                        self._count_moe(*map(np.asarray,
+                                             self.engine.moe_counts))
             for r in bucket:
                 r.batch, r.done = c.batches, True
                 done.append(r)
@@ -196,3 +229,11 @@ class BatchedServer:
             c.pad_tokens += len(bucket) * max(prompt) - sum(prompt)
             c.new_tokens += len(bucket) * n_new
         return done
+
+    def _count_moe(self, after_prefill, after_decode) -> None:
+        c = self.counts
+        for kind, (pairs, active) in (
+                ("prefill", after_prefill),
+                ("decode", after_decode - after_prefill)):
+            c.moe_pairs[kind] = c.moe_pairs.get(kind, 0) + int(pairs)
+            c.moe_active[kind] = c.moe_active.get(kind, 0) + int(active)

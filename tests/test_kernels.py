@@ -210,3 +210,65 @@ class TestPlatformAndVmem:
         plane = blocking.padded_plane_bytes(n, n, 4)
         assert s7.vmem_bytes(n, 4) >= 10.52 * plane
         assert lr.vmem_bytes(n, 4) >= 28.99 * plane
+
+
+# ----------------------------------------------------------------------
+# moe_gmm: grouped matmul over a MoE layer's held experts
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("sizes,rows,d,f", [
+    ([3, 0, 5, 1], 12, 128, 256),          # uneven, one empty, spare rows
+    ([0, 0, 0, 0], 8, 64, 128),            # no row for any group
+    ([20], 20, 128, 128),                  # one group over two row tiles
+    ([1, 30, 0, 2], 40, 256, 384),         # a group across tiles
+    ([0, 0, 17, 0], 300, 256, 128),        # prefill tiles of 128 rows
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_moe_gmm_matches_ref(sizes, rows, d, f, dtype):
+    from repro.kernels import moe_gmm
+    key = jax.random.PRNGKey(len(sizes) * rows)
+    x = jax.random.normal(key, (rows, d), jnp.float32).astype(dtype)
+    w = (jax.random.normal(jax.random.fold_in(key, 1), (len(sizes), d, f),
+                           jnp.float32) * d ** -0.5).astype(dtype)
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = np.asarray(moe_gmm.moe_gmm(x, w, gs), np.float32)
+    want = np.asarray(ref.grouped_matmul(x, w, gs), np.float32)
+    # bf16 outputs round to 8 bits of mantissa: 1/128 of values about 3
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    assert not np.any(got[sum(sizes):])        # rows past the groups: 0
+
+
+def test_moe_gmm_widens_narrow_weights_in_the_kernel():
+    """float32 rows on bfloat16 weights, as a float32 check of a bfloat16
+    model runs: the weights are widened exactly, a block at a time, so
+    the product is the float32 one on the widened weights."""
+    from repro.kernels import moe_gmm
+    key = jax.random.PRNGKey(7)
+    x = jax.random.normal(key, (24, 256), jnp.float32)
+    w = (jax.random.normal(jax.random.fold_in(key, 1), (3, 256, 128),
+                           jnp.float32) * 256 ** -0.5).astype(jnp.bfloat16)
+    gs = jnp.asarray([5, 0, 11], jnp.int32)
+    got = moe_gmm.moe_gmm(x, w, gs)
+    assert got.dtype == jnp.float32
+    want = ref.grouped_matmul(x, w.astype(jnp.float32), gs)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    assert moe_gmm.vmem_bytes(16, 1024, 1024, 4, 2) == (
+        2 * ((16 + 16) * 1024 * 4 + 1024 * 1024 * 2) + 1024 * 1024 * 4
+        + 2 * 16 * 1024 * 4)
+
+
+def test_moe_gmm_layout_aligns_groups_to_tiles():
+    """Each group starts on a row tile; rows past the groups go nowhere."""
+    from repro.kernels import moe_gmm
+    src, dest, tile_group, used = moe_gmm.layout(
+        jnp.asarray([3, 0, 17, 1], jnp.int32), 24, 16)
+    n_pad = (2 + 4) * 16
+    assert list(np.asarray(dest)) == (
+        [0, 1, 2] + list(range(16, 33)) + [48] + [n_pad] * 3)
+    assert int(used[0]) == 4
+    assert list(np.asarray(tile_group))[:4] == [0, 2, 2, 3]
+    # the inverse: each padded row's input row, 24 (none) for padding
+    want = np.full(n_pad, 24)
+    want[np.asarray(dest)[:21]] = np.arange(21)
+    np.testing.assert_array_equal(np.asarray(src), want)

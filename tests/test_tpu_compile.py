@@ -164,3 +164,56 @@ def test_decode_step_updates_kv_cache_in_place(one_chip):
             assert size < layers * k_layer, f"whole-cache write: {m[0]}"
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < stacked_k_bytes, (temp, stacked_k_bytes)
+
+
+@pytest.mark.parametrize("rows", [128, 8192], ids=["decode", "prefill"])
+@pytest.mark.parametrize("d,f", [(7168, 2048), (2048, 7168)],
+                         ids=["gate_up", "down"])
+def test_moe_gmm_compiles_at_cell_shapes(one_chip, rows, d, f):
+    """The held experts' grouped matmul at the deepseek-v3-ep32 cell's
+    widths over its 8 held experts: a decode step's 16 rows x 8 experts
+    per token (about 4-16 of them held here), a prefill chunk's 1024
+    tokens x 8 (about 4096 held in the 16 x 1024 prompt)."""
+    from repro.kernels import moe_gmm
+    compiled = _compile(
+        lambda x, w, g: moe_gmm.moe_gmm(x, w, g, interpret=False),
+        _spec((rows, d), jnp.bfloat16, one_chip),
+        _spec((8, d, f), jnp.bfloat16, one_chip),
+        _spec((8,), jnp.int32, one_chip))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _decode_text(model, one_chip, batch, rows):
+    """The compiled decode step's HLO text, donated caches."""
+    def spec(tree):
+        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                            abstract_tree(tree, default_dtype=jnp.bfloat16))
+
+    return jax.jit(model.decode_step, donate_argnums=1).lower(
+        spec(model.param_recs()), spec(model.cache_recs(batch, rows)),
+        _spec((batch, 1), jnp.int32, one_chip),
+        _spec((), jnp.int32, one_chip)).compile().as_text()
+
+
+def _cache_names(model) -> set:
+    return {jax.tree_util.keystr(p) for p, _ in
+            jax.tree_util.tree_flatten_with_path(model.cache_recs(1, 8))[0]}
+
+
+def test_phi3_decode_program_unchanged(one_chip):
+    """A model without a held-expert layer gains nothing from the expert
+    path: no ``moe_stats`` cache entry, no ``moe_gmm`` kernel and no op
+    from the ``moe/`` scopes in its decode program. A small held-share
+    model, compiled alike, has all three."""
+    from bench.drivers import serve_mla_moe
+    from test_deepseek_share import SMALL
+    phi3 = LM(dataclasses.replace(configs.get_config("phi3-mini-3.8b"),
+                                  n_layers=2, tp=1))
+    text = _decode_text(phi3, one_chip, 4, 2048)
+    assert not any("moe_stats" in n for n in _cache_names(phi3))
+    assert "moe_gmm" not in text and "/moe/" not in text
+    held = LM(serve_mla_moe.arch_config(dict(SMALL,
+                                             torch_dtype="bfloat16")))
+    text = _decode_text(held, one_chip, 4, 32)
+    assert any("moe_stats" in n for n in _cache_names(held))
+    assert "moe_gmm" in text and "/moe/route" in text
