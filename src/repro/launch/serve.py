@@ -119,9 +119,9 @@ def replay_logits(engine: Engine, requests: list[Request],
                                             caches)
             steps = [logits[:, -1, :vocab]]
             for j in range(1, n):
-                logits, caches = engine.decode(engine.params, caches,
-                                               fed[:, j - 1:j],
-                                               jnp.int32(s0 + j - 1))
+                logits, caches = engine.decode_logits(
+                    engine.params, caches, fed[:, j - 1:j],
+                    jnp.int32(s0 + j - 1))
                 steps.append(logits[:, -1, :vocab])
         got = np.asarray(jnp.stack(steps, axis=1), np.float32)
         for r, row in zip(bucket, got):

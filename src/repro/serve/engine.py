@@ -4,6 +4,12 @@ generate loop, and a request-queue driver (bucketed batching).
 decode_step lowers ONE new token against a ``max_len`` KV cache — this is
 the function the ``decode_32k`` / ``long_500k`` dry-run cells compile.
 
+Generated tokens stay on the device until their batch ends: each serving
+step samples inside its own program and writes the token into a
+``(batch, max_len)`` int32 buffer that the next step takes, donated, and
+:meth:`BatchedServer.drain` reads the whole buffer to the host in one
+transfer per batch.
+
 The submit/drain request-queue shape of :class:`BatchedServer` is reused
 by the analysis service tier (:class:`repro.service.AnalysisServer`),
 which drains queued analyze/sweep requests through a coalescing,
@@ -22,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.models.common import materialize, shardings
+from repro.models.common import constrain, materialize, shardings
 
 
 def make_caches(model, batch: int, max_len: int, key=None):
@@ -65,11 +71,37 @@ class Engine:
         def _prefill(params, batch, caches):
             return model.prefill(params, batch, caches, rule=rule)
 
-        def _decode(params, caches, tokens, pos):
+        def _decode_logits(params, caches, tokens, pos):
             return model.decode_step(params, caches, tokens, pos, rule=rule)
 
+        def _first(logits, key, pos, temperature):
+            tok = self._sample(logits, temperature, key).astype(jnp.int32)
+            out = jnp.zeros((tok.shape[0], max_len), jnp.int32)
+            if rule is not None:
+                out = constrain(out, rule, ("batch", None))
+            return tok, jax.lax.dynamic_update_slice(out, tok, (0, pos))
+
+        def _decode(params, caches, tok, pos, key, out, temperature):
+            key, sub = jax.random.split(key)
+            logits, caches = model.decode_step(params, caches, tok, pos,
+                                               rule=rule)
+            # the sample reads the logits as ``_decode_logits`` returns
+            # them: fused into the head's matmul, the argmax would pick
+            # from unrounded sums, and the served tokens would differ from
+            # the argmax of the replayed logits
+            logits = jax.lax.optimization_barrier(logits)
+            tok = self._sample(logits, temperature, sub).astype(jnp.int32)
+            out = jax.lax.dynamic_update_slice(out, tok, (0, pos + 1))
+            return caches, tok, key, out
+
         self.prefill = jax.jit(_prefill, donate_argnums=2)
-        self.decode = jax.jit(_decode, donate_argnums=1)
+        #: the prefill's logits -> (first token, token buffer holding it)
+        self.first = jax.jit(_first, static_argnums=3)
+        #: one serving step: decode, sample, write the token into the buffer
+        self.decode = jax.jit(_decode, donate_argnums=(1, 5),
+                              static_argnums=6)
+        #: one decode step that returns its logits (``replay_logits``)
+        self.decode_logits = jax.jit(_decode_logits, donate_argnums=1)
         #: (after prefill, after the last decode step) of the last
         #: generate call: the held experts' routed pairs and active experts,
         #: on the device; None for a model without a held-expert layer
@@ -92,6 +124,8 @@ class Engine:
         return jax.device_put(caches, shardings(recs, self.mesh, self.rule))
 
     def _sample(self, logits, temperature: float, key):
+        """(b, 1) next tokens from (b, s, V) logits; traced inside the
+        serving programs, with ``temperature`` static."""
         if temperature == 0.0:
             return jnp.argmax(logits[:, -1], axis=-1)[:, None]
         probs = jax.nn.softmax(logits[:, -1].astype(jnp.float32)
@@ -100,12 +134,15 @@ class Engine:
             key, jnp.log(probs + 1e-9), axis=-1)[:, None]
 
     def generate(self, tokens, n_new: int, temperature: float = 0.0,
-                 key=None, extras: dict | None = None) -> list:
-        """tokens: (b, s0) int32 prompt. Returns the generated ids as they
-        are made: ``n_new`` (b, 1) arrays, still on the device. Spans
-        ``serve.prefill`` (new caches, prefill, first sample) and one
-        ``serve.decode_step`` per further token (key split, decode,
-        sample)."""
+                 key=None, extras: dict | None = None):
+        """tokens: (b, s0) int32 prompt. Returns the token buffer, still on
+        the device: (b, ``max_len``) int32 whose columns s0 .. s0+n_new-1
+        hold the generated ids by sequence position (the prompt's columns
+        are 0). No step waits for the host: each samples inside its program
+        and writes into the buffer, so the tokens reach the host only when
+        the caller reads the buffer. Spans ``serve.prefill`` (new caches,
+        prefill, first sample) and one ``serve.decode_step`` per further
+        token (the dispatch of ``_decode``)."""
         b, s0 = tokens.shape
         assert s0 + n_new <= self.max_len, (s0, n_new, self.max_len)
         key = jax.random.PRNGKey(0) if key is None else key
@@ -114,17 +151,14 @@ class Engine:
                 caches = self.new_caches(b)
                 batch = {"tokens": tokens, **(extras or {})}
                 logits, caches = self.prefill(self.params, batch, caches)
-                tok = self._sample(logits, temperature, key)
+                tok, out = self.first(logits, key, np.int32(s0), temperature)
                 if self._moe_totals is not None:
                     after_prefill = self._moe_totals(caches)
-            out = [tok]
             for i in range(1, n_new):
                 with obs.span("serve.decode_step", step=i):
-                    key, sub = jax.random.split(key)
-                    logits, caches = self.decode(self.params, caches, tok,
-                                                 jnp.int32(s0 + i - 1))
-                    tok = self._sample(logits, temperature, sub)
-                out.append(tok)
+                    caches, tok, key, out = self.decode(
+                        self.params, caches, tok, np.int32(s0 + i - 1), key,
+                        out, temperature)
             if self._moe_totals is not None:
                 self.moe_counts = (after_prefill, self._moe_totals(caches))
         return out
@@ -159,6 +193,7 @@ class ServeCounts:
     prompt_tokens: int = 0      # the requests' own prompt tokens
     pad_tokens: int = 0         # left padding up to each batch's longest
     new_tokens: int = 0         # tokens the engine made, rows x steps
+    readbacks: int = 0          # device-to-host transfers of token buffers
     #: held-expert layers only, by call kind ("prefill", "decode"): routed
     #: (token, expert) pairs the held experts computed, and held experts
     #: that got at least one pair, summed over layers and calls
@@ -187,9 +222,12 @@ class BatchedServer:
 
         Each bucket is one ``serve.batch`` span (:mod:`repro.obs`) around
         the engine's ``serve.prefill`` and ``serve.decode_step`` spans and a
-        ``serve.readback`` span, which joins the tokens and reads them to
-        the host once the last step is done, so that it times the transfer
-        and not the wait for queued steps; :attr:`counts` adds it up."""
+        ``serve.readback`` span. The bucket's tokens stay in the engine's
+        device buffer until its last step is done; the read-back span then
+        moves the buffer (and any held-expert counts) to the host in one
+        transfer and slices each request's tokens there, so that it times
+        the transfer and not the wait for queued steps; :attr:`counts` adds
+        it up."""
         done = []
         while not self._queue.empty():
             bucket: list[Request] = []
@@ -209,17 +247,18 @@ class BatchedServer:
             with obs.span("serve.batch", batch=c.batches, rows=len(bucket),
                           uids=tuple(r.uid for r in bucket),
                           prompt_len=max(prompt), n_new=n_new):
-                steps = self.engine.generate(
+                out = self.engine.generate(
                     left_pad([r.tokens for r in bucket]), n_new)
-                jax.block_until_ready(steps[-1])
+                jax.block_until_ready((out, self.engine.moe_counts))
                 with obs.span("serve.readback",
                               tokens=sum(r.max_new for r in bucket)):
-                    gen = jnp.concatenate(steps, axis=1)
+                    gen, moe = jax.device_get((out, self.engine.moe_counts))
+                    s0 = max(prompt)        # the padded prompt's width
                     for i, r in enumerate(bucket):
-                        r.result = [int(t) for t in gen[i][:r.max_new]]
-                    if self.engine.moe_counts is not None:
-                        self._count_moe(*map(np.asarray,
-                                             self.engine.moe_counts))
+                        r.result = gen[i, s0:s0 + r.max_new].tolist()
+                    if moe is not None:
+                        self._count_moe(*moe)
+                c.readbacks += 1
             for r in bucket:
                 r.batch, r.done = c.batches, True
                 done.append(r)

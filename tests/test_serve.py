@@ -12,9 +12,13 @@ from repro.serve import Engine
 from repro.serve.engine import BatchedServer, Request
 
 
-def _generate(eng, toks, *args, **kwargs):
-    """The engine's per-step tokens joined into one (b, n_new) array."""
-    return jnp.concatenate(eng.generate(toks, *args, **kwargs), axis=1)
+def _generate(eng, toks, n_new, **kwargs):
+    """The generated tokens as one (b, n_new) array: the columns of the
+    engine's token buffer after the prompt."""
+    out = eng.generate(toks, n_new, **kwargs)
+    assert out.shape == (toks.shape[0], eng.max_len)
+    s0 = toks.shape[1]
+    return out[:, s0:s0 + n_new]
 
 
 @pytest.fixture(scope="module")
@@ -64,6 +68,35 @@ def test_batched_server(setup):
     assert srv.counts.requests == 7
     assert srv.counts.prompt_tokens == 21 and srv.counts.pad_tokens == 0
     assert srv.counts.new_tokens == 28
+
+
+def test_serving_compiles_once_across_answer_lengths(setup):
+    """Answers of 2 and then 7 tokens run the same first-token and decode
+    programs, and each batch reads its tokens back in one transfer."""
+    cfg, model, params = setup
+    eng = Engine(model, params, max_len=64)
+    srv = BatchedServer(eng, batch_size=2)
+    for max_new in (2, 7):
+        for i in range(2):
+            srv.submit(Request(uid=i, tokens=[1 + i, 2, 3], max_new=max_new))
+        done = srv.drain()
+        assert [len(r.result) for r in done] == [max_new] * 2
+    assert eng.first._cache_size() == 1
+    assert eng.decode._cache_size() == 1
+    assert srv.counts.readbacks == srv.counts.batches == 2
+
+
+def test_serving_step_lowers_as_decode(setup):
+    """The benchmark finds the serving step's device time by the program
+    name ``jit__decode``."""
+    cfg, model, params = setup
+    eng = Engine(model, params, max_len=16)
+    caches = eng.new_caches(2)
+    tok = jnp.zeros((2, 1), jnp.int32)
+    out = jnp.zeros((2, eng.max_len), jnp.int32)
+    text = eng.decode.lower(eng.params, caches, tok, np.int32(3),
+                            jax.random.PRNGKey(0), out, 0.0).as_text()
+    assert "@jit__decode" in text
 
 
 def test_temperature_sampling_reproducible(setup):
