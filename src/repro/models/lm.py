@@ -150,6 +150,9 @@ class LM:
         # Megatron-style vocab padding: lane-aligned (128) so the vocab dim
         # shards evenly over any TP degree; padded logits are masked in _head.
         self.padded_vocab = -(-cfg.vocab // 128) * 128
+        #: caches -> the held experts' counts, summed (moe.held_counts);
+        #: None when no layer holds a share of the experts
+        self.held_counts = moe.held_counts(cfg)
 
     # -- parameters ------------------------------------------------------
     def param_recs(self):
@@ -232,11 +235,8 @@ class LM:
                                        _seq_axes("latent"), init="zeros"),
                         "k_rope": PRec((batch, 1, m.qk_rope_dim, max_len),
                                        _seq_axes("k_rope"), init="zeros")}
-            if blk.kind == "moe" and cfg.moe.ep_size:
-                # the held experts' pairs and active experts, summed over
-                # the calls that wrote this cache (Engine reads them)
-                return {"moe_stats": PRec((2,), (None,), dtype=jnp.int32,
-                                          init="zeros")}
+            if blk.kind == "moe":
+                return moe.moe_cache_recs(cfg)
             if blk.kind == "mamba":
                 shapes = mamba2.mamba2_cache_shape(cfg, batch)
                 return {"ssm": PRec(shapes["ssm"][0],
@@ -286,13 +286,10 @@ class LM:
             return x + dx, c
         if blk.kind == "mlp":
             return x + mlp.mlp_apply(p, x, cfg, rule=rule), cache
-        if blk.kind == "moe" and cfg.moe.ep_size:
-            dx, stats = moe.held_apply(p, x, cfg, layer=layer)
-            if cache is not None:
-                cache = {"moe_stats": cache["moe_stats"] + stats}
-            return x + dx, cache
         if blk.kind == "moe":
-            return x + moe.moe_apply(p, x, cfg, rule=rule), cache
+            dx, c = moe.block_apply(p, x, cfg, rule=rule, cache=cache,
+                                    layer=layer)
+            return x + dx, c
         if blk.kind == "mamba":
             dx, c = mamba2.mamba2_apply(p, x, cfg, rule=rule, cache=cache,
                                         pos=pos)
@@ -320,11 +317,9 @@ class LM:
         new_caches = []
         for si, st in enumerate(self.stages):
             pstack = params["stages"][si]["blocks"]
-            # a held-expert layer's matrices stay stacked, outside the
-            # scan's xs: its kernel reads layer i where it lies, where a
-            # per-layer slice would be copied whole for the custom call
-            stacks = [{k: p[k] for k in moe.EXPERT_WEIGHTS}
-                      if blk.kind == "moe" and self.cfg.moe.ep_size else {}
+            # weights a block keeps stacked, outside the scan's xs
+            stacks = [{k: p[k] for k in moe.scan_stacked(self.cfg)}
+                      if blk.kind == "moe" else {}
                       for blk, p in zip(st.blocks, pstack)]
             pstack = [{k: v for k, v in p.items() if k not in w}
                       for p, w in zip(pstack, stacks)]

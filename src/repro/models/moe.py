@@ -1,13 +1,19 @@
-"""Mixture-of-Experts FFN: top-k routing with static capacity (GShard-style
-one-hot dispatch → XLA all-to-all under expert parallelism), shared experts,
-and DeepSeek-V3's aux-loss-free sigmoid routing with a learned bias.
+"""Mixture-of-Experts FFN: one router and two routed-expert layers, behind
+one block that the LM calls without knowing which layer it runs.
 
-Experts are sharded over the `model` axis (EP); the dispatch/combine einsums
-contract the token dim (sharded over `data`), which XLA lowers to the
-canonical all-to-all + all-reduce pattern of expert parallelism.
+:func:`route_topk` routes every token to ``top_k`` of ``n_experts`` for
+both layers, with one of three routers: ``softmax``, ``sigmoid_bias``
+(DeepSeek's aux-loss-free routing: a learned bias shifts the choice only)
+and ``noaux_tc`` (DeepSeek-V3's group-limited form of it).
 
-A layer with ``ep_size`` set is one rank's share of an expert-parallel
-deployment (:func:`held_apply`): it holds experts ``ep_rank * n_held ..
+:func:`moe_apply`, the capacity layer, holds every expert, each with a
+static capacity per token group (GShard); the dispatch's (group <->
+expert) transpose is the all-to-all of expert parallelism, experts
+sharded over `model`, tokens over `data`. It is the trainable path: the
+held layer's ``moe_gmm`` kernel has no backward pass.
+
+:func:`held_apply`, the held layer (``ep_size`` set), is one rank's share
+of an expert-parallel deployment: it holds experts ``ep_rank * n_held ..
 (ep_rank + 1) * n_held - 1`` of ``n_experts``, routes over all of them,
 and computes, without dropping a token, only what its own experts add,
 through the ``moe_gmm`` grouped-matmul kernel; the shared expert is added
@@ -77,86 +83,150 @@ def moe_recs(cfg) -> dict[str, PRec]:
     return recs
 
 
-def _topk_mask(scores, k):
-    """scores: (T, E) -> (weights (T,E), mask (T,E))  [k-hot]"""
-    vals, idx = jax.lax.top_k(scores, k)
-    mask = jax.nn.one_hot(idx, scores.shape[-1], dtype=scores.dtype).sum(1)
-    return mask
+def moe_cache_recs(cfg) -> dict[str, PRec]:
+    """A block's cache: for a held layer, the held experts' pairs and
+    active experts, summed over the calls that wrote it; nothing for a
+    capacity layer."""
+    if not cfg.moe.ep_size:
+        return {}
+    return {"moe_stats": PRec((2,), (None,), dtype=jnp.int32, init="zeros")}
 
 
-def _route(p, xt, m: MoEConfig):
-    """Router: returns (weights (t, e), khot (t, e), idx (t, k))."""
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"])
-    if m.router == "sigmoid_bias":
-        # DeepSeek aux-loss-free: bias only affects selection, not weights
-        sel_scores = jax.nn.sigmoid(logits) + p["router_bias"]
-        gate_scores = jax.nn.sigmoid(logits)
-    else:
-        sel_scores = logits
-        gate_scores = jax.nn.softmax(logits, axis=-1)
-    _, idx = jax.lax.top_k(sel_scores, m.top_k)
-    khot = jax.nn.one_hot(idx, m.n_experts,
-                          dtype=gate_scores.dtype).sum(1)    # (t, e)
-    weights = gate_scores * khot
-    if m.router == "sigmoid_bias":                            # renormalize
-        weights = weights / (weights.sum(-1, keepdims=True) + 1e-9)
-    return weights, khot, idx
+def scan_stacked(cfg) -> tuple[str, ...]:
+    """The block's weights that stay stacked outside the layer scan: a held
+    layer's kernel reads layer ``i`` where it lies, where a per-layer slice
+    would be copied whole for the custom call."""
+    return EXPERT_WEIGHTS if cfg.moe.ep_size else ()
 
 
-def moe_apply(p, x, cfg, rule=None, dispatch: str = "scatter"):
-    """x: (b, s, d). Static-capacity top-k dispatch, canonical GShard
-    group-local form: tokens are split into G groups (one per data shard,
-    ``rule['moe_groups']``), routing positions and capacity are computed
-    *within* the group, dispatch/combine scatters stay group-local, and the
-    (group <-> expert) transpose between the dispatch buffer and the expert
-    FFN is the one true all-to-all of expert parallelism.
+def block_apply(p, x, cfg, rule=None, cache=None, layer=None):
+    """The MoE block, x: (b, s, d): ``(what it adds to x, cache)``. A held
+    layer adds its counts to the cache's; a capacity layer's cache is
+    empty and passes through."""
+    if not cfg.moe.ep_size:
+        return moe_apply(p, x, cfg, rule=rule), cache
+    dx, stats = held_apply(p, x, cfg, layer=layer)
+    if cache is not None:
+        cache = {"moe_stats": cache["moe_stats"] + stats}
+    return dx, cache
 
-    dispatch='scatter' (default): matmul-free dispatch/combine via
-    scatter-add/gather in (token, k) pair space. The classic one-hot einsum
-    dispatch costs 2·t_g·(e·c_g)·d ≈ 2.5·k·t_g²·d MXU flops per group —
-    ~800x the useful expert compute at deepseek-v3 scale when G=1 (t=1M);
-    it is kept (dispatch='einsum') for small configs and the equivalence
-    test (the two paths are numerically identical).
-    """
-    m: MoEConfig = cfg.moe
+
+def held_counts(cfg):
+    """The function from a model's caches to its held experts' pairs and
+    active experts, (2,) int32, summed over every held layer and the calls
+    that wrote the caches; None when no layer holds a share."""
+    if not (cfg.moe and cfg.moe.ep_size):
+        return None
+
+    def totals(caches):
+        leaves = [leaf for path, leaf in
+                  jax.tree_util.tree_flatten_with_path(caches)[0]
+                  if getattr(path[-1], "key", None) == "moe_stats"]
+        return sum(leaf.reshape(-1, 2).sum(0) for leaf in leaves)
+    return totals
+
+
+def _ffn(p, x, cfg, routed, rule=None):
+    """A routed-expert FFN on x: (b, s, d). ``routed`` takes the normed
+    tokens, (t, d), and returns what the routed experts add, (t, d), and a
+    count of its own; the shared expert is added to the first. Returns
+    ``(out (b, s, d), count)``."""
     b, s, d = x.shape
-    xn = rms_norm(x, p["ln"])
-    t = b * s
+    out, count = routed(rms_norm(x, p["ln"]).reshape(b * s, d))
+    out = out.reshape(b, s, d)
+    if cfg.moe.n_shared:
+        out = out + mlp_apply(p["shared"], x, cfg, rule=rule)
+    if rule is not None:
+        out = constrain(out, rule, ("batch", "seq", "act_embed"))
+    return out, count
+
+
+def route_topk(p, xt, m: MoEConfig):
+    """Tokens ``xt`` (t, d) over all ``n_experts``: (experts (t, k) int32,
+    combine weights (t, k) float32).
+
+    ``softmax``: the ``top_k`` best logits, weighted by the softmax over
+    all experts, not renormalised. ``sigmoid_bias``: ``s = sigmoid(x W)``;
+    the ``top_k`` best ``s + bias`` are chosen (the bias shifts the choice
+    only), weighted by their ``s`` normalised to sum 1. ``noaux_tc``: a
+    group's score is the sum of its two best ``s + bias`` and only the
+    ``topk_group`` best groups' experts compete; the choice and weights are
+    then as for ``sigmoid_bias``, times ``routed_scale``. The router's
+    product is computed in full float32, as its weights are stored: a TPU's
+    default would round both operands to bfloat16 and flip near-tied
+    choices."""
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    if m.router == "softmax":
+        _, idx = jax.lax.top_k(logits, m.top_k)
+        return idx, jnp.take_along_axis(jax.nn.softmax(logits, axis=-1),
+                                        idx, axis=1)
+    s = jax.nn.sigmoid(logits)
+    sel = s + p["router_bias"]
+    if m.router == "sigmoid_bias":
+        _, idx = jax.lax.top_k(sel, m.top_k)
+        w = jnp.take_along_axis(s, idx, axis=1)
+        return idx, w / (w.sum(-1, keepdims=True) + 1e-9)
+    if m.router != "noaux_tc":
+        raise ValueError(f"unknown router {m.router!r}")
+    t, e = sel.shape
+    groups = sel.reshape(t, m.n_group, e // m.n_group)
+    g_score = jax.lax.top_k(groups, 2)[0].sum(-1)               # (t, G)
+    _, g_idx = jax.lax.top_k(g_score, m.topk_group)
+    keep = jax.nn.one_hot(g_idx, m.n_group, dtype=jnp.int32).sum(1)
+    keep = jnp.repeat(keep > 0, e // m.n_group, axis=1)        # (t, e)
+    _, idx = jax.lax.top_k(jnp.where(keep, sel, -jnp.inf), m.top_k)
+    w = jnp.take_along_axis(s, idx, axis=1)
+    return idx, w / (w.sum(-1, keepdims=True) + 1e-20) * m.routed_scale
+
+
+def moe_apply(p, x, cfg, rule=None):
+    """The capacity layer, x: (b, s, d). Static-capacity top-k dispatch,
+    canonical GShard group-local form: tokens are split into G groups (one
+    per data shard, ``rule['moe_groups']``), routing positions and capacity
+    are computed *within* the group, dispatch/combine scatters stay
+    group-local, and the (group <-> expert) transpose between the dispatch
+    buffer and the expert FFN is the one true all-to-all of expert
+    parallelism. Dispatch and combine are scatter-add/gather in (token, k)
+    pair space, free of matmuls: the classic one-hot einsum dispatch costs
+    2·t_g·(e·c_g)·d ≈ 2.5·k·t_g²·d MXU flops per group, ~800x the useful
+    expert compute at deepseek-v3 scale when G=1 (t=1M)."""
+    out, _ = _ffn(p, x, cfg,
+                  lambda xt: (_capacity_part(p, xt, cfg.moe, rule), None),
+                  rule)
+    return out
+
+
+def _capacity_part(p, xt, m: MoEConfig, rule=None):
+    """Tokens ``xt`` (t, d), normed: what the routed experts add, (t, d),
+    each expert taking at most its capacity of a group's pairs."""
+    t, d = xt.shape
     G = (rule or {}).get("moe_groups", 1)
     if t % G:
         G = 1
     tg = t // G
-    xt = xn.reshape(G, tg, d)
-    weights, khot, idx = _route(p, xt.reshape(t, d), m)
-    weights = weights.reshape(G, tg, m.n_experts)
-    khot = khot.reshape(G, tg, m.n_experts)
-    idx = idx.reshape(G, tg, m.top_k)
+    idx, w = route_topk(p, xt, m)
+    idx, w = idx.reshape(G, tg, m.top_k), w.reshape(G, tg, m.top_k)
+    xt = xt.reshape(G, tg, d)
 
     # floor 8: tiny decode groups otherwise drop colliding tokens
     capacity = max(min(8, tg), int(m.capacity_factor * m.top_k * tg
                                    / m.n_experts))
     # position of each token within its expert's group-local buffer
-    pos_te = (jnp.cumsum(khot, axis=1) - khot).astype(jnp.int32)  # (G,tg,e)
-
-    if dispatch == "einsum":
-        keep = (pos_te < capacity) & (khot > 0)
-        disp = jax.nn.one_hot(jnp.where(keep, pos_te, capacity),
-                              capacity, dtype=x.dtype)        # (G,tg,e,c)
-        comb = disp * weights.astype(x.dtype)[..., None]
-        xin = jnp.einsum("gtec,gtd->gecd", disp, xt)
-    else:
-        # scatter dispatch in (token, k) pair space; overflow pairs land in
-        # the per-expert spill slot (index `capacity`), dropped afterwards
-        pos_k = jnp.take_along_axis(pos_te, idx, axis=2)      # (G, tg, k)
-        keep_k = pos_k < capacity
-        pos_k = jnp.where(keep_k, pos_k, capacity)
-        slot = idx * (capacity + 1) + pos_k                   # (G, tg, k)
-        src = jnp.broadcast_to(xt[:, :, None, :], (G, tg, m.top_k, d))
-        zeros = jnp.zeros((G, m.n_experts * (capacity + 1), d), x.dtype)
-        xin = jax.vmap(lambda z, sl, sr: z.at[sl].add(sr))(
-            zeros, slot.reshape(G, tg * m.top_k),
-            src.reshape(G, tg * m.top_k, d))
-        xin = xin.reshape(G, m.n_experts, capacity + 1, d)[:, :, :capacity]
+    khot = jax.nn.one_hot(idx, m.n_experts, dtype=jnp.int32).sum(2)
+    pos_te = jnp.cumsum(khot, axis=1) - khot                   # (G,tg,e)
+    # scatter dispatch in (token, k) pair space; overflow pairs land in
+    # the per-expert spill slot (index `capacity`), dropped afterwards
+    pos_k = jnp.take_along_axis(pos_te, idx, axis=2)          # (G, tg, k)
+    keep_k = pos_k < capacity
+    pos_k = jnp.where(keep_k, pos_k, capacity)
+    slot = idx * (capacity + 1) + pos_k                       # (G, tg, k)
+    src = jnp.broadcast_to(xt[:, :, None, :], (G, tg, m.top_k, d))
+    zeros = jnp.zeros((G, m.n_experts * (capacity + 1), d), xt.dtype)
+    xin = jax.vmap(lambda z, sl, sr: z.at[sl].add(sr))(
+        zeros, slot.reshape(G, tg * m.top_k),
+        src.reshape(G, tg * m.top_k, d))
+    xin = xin.reshape(G, m.n_experts, capacity + 1, d)[:, :, :capacity]
 
     # (G, e, c, d) -> (e, G, c, d): the EP all-to-all (groups live on the
     # data axis, experts on the model axis)
@@ -171,54 +241,16 @@ def moe_apply(p, x, cfg, rule=None, dispatch: str = "scatter"):
         eout = constrain(eout, rule, ("act_experts", "batch", None, None))
     eout = eout.swapaxes(0, 1)                                # a2a back
 
-    eout = eout.astype(x.dtype)     # combine in bf16: halves the a2a/AR wire
-    if dispatch == "einsum":
-        out = jnp.einsum("gecd,gtec->gtd", eout, comb)
-    else:
-        pad = jnp.zeros((G, m.n_experts, 1, d), eout.dtype)
-        flat = jnp.concatenate([eout, pad], axis=2) \
-            .reshape(G, m.n_experts * (capacity + 1), d)
-        gathered = jnp.take_along_axis(
-            flat, slot.reshape(G, tg * m.top_k)[..., None], axis=1) \
-            .reshape(G, tg, m.top_k, d)
-        w_k = (jnp.take_along_axis(weights, idx, axis=2)
-               * keep_k).astype(x.dtype)                      # (G, tg, k)
-        out = jnp.einsum("gtkd,gtk->gtd", gathered, w_k)
-    out = out.reshape(b, s, d)
-
-    if m.n_shared:
-        out = out + mlp_apply(p["shared"], x, cfg, rule=rule)
-    if rule is not None:
-        out = constrain(out, rule, ("batch", "seq", "act_embed"))
-    return out
-
-
-def route_topk(p, xt, m: MoEConfig):
-    """DeepSeek-V3's ``noaux_tc`` router over all ``n_experts``: (experts
-    (t, k) int32, combine weights (t, k) float32).
-
-    ``s = sigmoid(x W)``, ``s' = s + bias``; a group's score is the sum of
-    its two best ``s'`` and only the ``topk_group`` best groups' experts
-    compete; the ``top_k`` best ``s'`` are chosen, weighted by their ``s``
-    normalised to sum 1, times ``routed_scale``. The router's product is
-    computed in full float32, as its weights are stored: a TPU's default
-    would round both operands to bfloat16 and flip near-tied choices."""
-    if m.router != "noaux_tc":
-        raise ValueError(f"held experts route with 'noaux_tc', "
-                         f"not {m.router!r}")
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"],
-                        precision=jax.lax.Precision.HIGHEST)
-    s = jax.nn.sigmoid(logits)
-    sel = s + p["router_bias"]
-    t, e = sel.shape
-    groups = sel.reshape(t, m.n_group, e // m.n_group)
-    g_score = jax.lax.top_k(groups, 2)[0].sum(-1)               # (t, G)
-    _, g_idx = jax.lax.top_k(g_score, m.topk_group)
-    keep = jax.nn.one_hot(g_idx, m.n_group, dtype=jnp.int32).sum(1)
-    keep = jnp.repeat(keep > 0, e // m.n_group, axis=1)        # (t, e)
-    _, idx = jax.lax.top_k(jnp.where(keep, sel, -jnp.inf), m.top_k)
-    w = jnp.take_along_axis(s, idx, axis=1)
-    return idx, w / (w.sum(-1, keepdims=True) + 1e-20) * m.routed_scale
+    eout = eout.astype(xt.dtype)    # combine in bf16: halves the a2a/AR wire
+    pad = jnp.zeros((G, m.n_experts, 1, d), eout.dtype)
+    flat = jnp.concatenate([eout, pad], axis=2) \
+        .reshape(G, m.n_experts * (capacity + 1), d)
+    gathered = jnp.take_along_axis(
+        flat, slot.reshape(G, tg * m.top_k)[..., None], axis=1) \
+        .reshape(G, tg, m.top_k, d)
+    out = jnp.einsum("gtkd,gtk->gtd", gathered,
+                     (w * keep_k).astype(xt.dtype))
+    return out.reshape(t, d)
 
 
 def _held_part(p, xt, m: MoEConfig, layer=None):
@@ -261,30 +293,16 @@ def held_apply(p, x, cfg, layer=None):
     ``layer``, ``p``'s :data:`EXPERT_WEIGHTS` are a layer stack and
     ``layer`` picks from it."""
     m: MoEConfig = cfg.moe
-    b, s, d = x.shape
-    xt = rms_norm(x, p["ln"]).reshape(b * s, d)
-    t = b * s
-    c = HELD_CHUNK if t > HELD_CHUNK and t % HELD_CHUNK == 0 else t
-    if c == t:
-        out, sizes = _held_part(p, xt, m, layer)
-    else:
+
+    def routed(xt):
+        t, d = xt.shape
+        c = HELD_CHUNK if t > HELD_CHUNK and t % HELD_CHUNK == 0 else t
+        if c == t:
+            return _held_part(p, xt, m, layer)
         out, sizes = jax.lax.map(lambda xc: _held_part(p, xc, m, layer),
                                  xt.reshape(t // c, c, d))
-        out, sizes = out.reshape(t, d), sizes.sum(0)
-    out = out.reshape(b, s, d)
-    if m.n_shared:
-        out = out + mlp_apply(p["shared"], x, cfg)
+        return out.reshape(t, d), sizes.sum(0)
+
+    out, sizes = _ffn(p, x, cfg, routed)
     stats = jnp.stack([sizes.sum(), (sizes > 0).sum()]).astype(jnp.int32)
     return out, stats
-
-
-def load_balance_stats(p, x, cfg):
-    """Router entropy/load diagnostics (for logging; not an aux loss when
-    router='sigmoid_bias' — DeepSeek-V3 trains aux-free)."""
-    m = cfg.moe
-    xt = rms_norm(x, p["ln"]).reshape(-1, cfg.d_model)
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, -1)
-    load = probs.mean(0)
-    return {"router_entropy": -(load * jnp.log(load + 1e-9)).sum(),
-            "max_load": load.max() * m.n_experts}

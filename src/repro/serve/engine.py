@@ -8,13 +8,8 @@ Generated tokens stay on the device until their batch ends: each serving
 step samples inside its own program and writes the token into a
 ``(batch, max_len)`` int32 buffer that the next step takes, donated, and
 :meth:`BatchedServer.drain` reads the whole buffer to the host in one
-transfer per batch.
-
-The submit/drain request-queue shape of :class:`BatchedServer` is reused
-by the analysis service tier (:class:`repro.service.AnalysisServer`),
-which drains queued analyze/sweep requests through a coalescing,
-disk-cached :class:`repro.service.AnalysisService` instead of a token
-generator.
+transfer per batch, with the held experts' counts of a model that has
+held-expert layers (``LM.held_counts``).
 """
 from __future__ import annotations
 
@@ -106,8 +101,8 @@ class Engine:
         #: generate call: the held experts' routed pairs and active experts,
         #: on the device; None for a model without a held-expert layer
         self.moe_counts = None
-        self._moe_totals = (jax.jit(_moe_totals) if _has_moe_stats(
-            model.cache_recs(1, 1)) else None)
+        self._moe_totals = (jax.jit(model.held_counts)
+                            if model.held_counts else None)
 
     def mesh_context(self):
         """The context the engine's steps run in: its mesh, if any."""
@@ -162,20 +157,6 @@ class Engine:
             if self._moe_totals is not None:
                 self.moe_counts = (after_prefill, self._moe_totals(caches))
         return out
-
-
-def _has_moe_stats(tree) -> bool:
-    return any(getattr(k, "key", None) == "moe_stats"
-               for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]
-               for k in path)
-
-
-def _moe_totals(caches):
-    """(2,) int32: every held-expert layer's ``moe_stats`` summed."""
-    leaves = [leaf for path, leaf in
-              jax.tree_util.tree_flatten_with_path(caches)[0]
-              if getattr(path[-1], "key", None) == "moe_stats"]
-    return sum(leaf.reshape(-1, 2).sum(0) for leaf in leaves)
 
 
 def left_pad(prompts: list[list[int]]):
