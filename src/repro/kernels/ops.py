@@ -49,7 +49,8 @@ def stencil3d7pt(a, coeffs, interpret: bool | None = None):
 
 
 def longrange3d(u, v, roc, coeffs, interpret: bool | None = None):
-    """Validates the 11-plane (9 V + U + ROC) working set fits VMEM."""
+    """Validates the working set (the 9-plane V ring, and the V, U, ROC and
+    output planes in flight) fits VMEM."""
     N = u.shape[-1]
     _check_vmem("longrange3d", _lr.vmem_bytes(N, u.dtype.itemsize), 4,
                 u.shape, 3, u.dtype.itemsize)

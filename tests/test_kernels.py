@@ -40,6 +40,32 @@ def test_longrange_sweep(shape):
                                rtol=2e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("n", [16, 24, 37])
+@pytest.mark.parametrize("m", [9, 12, 17])
+def test_longrange_ring_planes(m, n):
+    """The V ring from the first interior plane (M = 9 = 2r+1) up, N off
+    the sublane tile: the four boundary planes at each end and the width-4
+    rim of every plane equal U exactly; the interior matches the oracle."""
+    key = jax.random.PRNGKey(m * 100 + n)
+    shape = (m, n, n)
+    u = jax.random.normal(key, shape, jnp.float32)
+    v = jax.random.normal(jax.random.fold_in(key, 1), shape, jnp.float32)
+    roc = jax.random.normal(jax.random.fold_in(key, 2), shape,
+                            jnp.float32) * 0.1
+    c = jnp.array([0.5, 0.1, 0.05, 0.02, 0.01], jnp.float32)
+    out = np.asarray(longrange3d(u, v, roc, c))
+    want = np.asarray(ref.longrange3d(u, v, roc, c))
+    u = np.asarray(u)
+    r = 4
+    np.testing.assert_array_equal(out[:r], u[:r])
+    np.testing.assert_array_equal(out[m - r:], u[m - r:])
+    for rim in (np.s_[:, :r], np.s_[:, n - r:], np.s_[:, :, :r],
+                np.s_[:, :, n - r:]):
+        np.testing.assert_array_equal(out[rim], u[rim])
+    inner = np.s_[r:m - r, r:n - r, r:n - r]
+    np.testing.assert_allclose(out[inner], want[inner], rtol=2e-4, atol=1e-5)
+
+
 @settings(max_examples=10, deadline=None)
 @given(m=st.integers(3, 10), n=st.integers(3, 24))
 def test_stencil7pt_property(m, n):
@@ -189,12 +215,12 @@ class TestPlatformAndVmem:
             7 * 8 * 128 * 4 + blocking.PLANE_VMEM_HEADROOM
 
     def test_longrange_refused_before_compile(self):
-        """N=1040 needs 133.5 MiB: refused by the wrapper while tracing,
+        """N=1032 needs 132.52 MiB: refused by the wrapper while tracing,
         with the advisor's suggestion, before any kernel is built."""
-        spec = jax.ShapeDtypeStruct((9, 1040, 1040), jnp.float32)
+        spec = jax.ShapeDtypeStruct((9, 1032, 1032), jnp.float32)
         c = jax.ShapeDtypeStruct((5,), jnp.float32)
         with pytest.raises(ValueError,
-                           match=r"133\.54 MiB.*advisor suggests"):
+                           match=r"132\.52 MiB.*advisor suggests"):
             jax.eval_shape(longrange3d, spec, spec, spec, c)
         ok = jax.ShapeDtypeStruct((9, 1024, 1024), jnp.float32)
         assert jax.eval_shape(longrange3d, ok, ok, ok, c).shape == ok.shape
@@ -202,14 +228,17 @@ class TestPlatformAndVmem:
     @pytest.mark.parametrize("n", [8, 128, 1015, 1024])
     def test_kernel_vmem_counts_cover_compiler(self, n):
         """The counts stay above what the v5e compiler was found to
-        allocate: 10.52 planes (stencil3d7pt) and 28.99 planes plus
-        nothing more than 1 MiB (longrange3d)."""
+        allocate: 10.52 planes (stencil3d7pt), and for longrange3d's VMEM
+        ring the smallest ``vmem_limit_bytes`` (to 1/64 MiB) under which a
+        deviceless compile at M=130 passed: 12.0, 19.25, 28.996 and
+        28.359 planes at N = 8, 128, 1015, 1024."""
         import importlib
         s7 = importlib.import_module("repro.kernels.stencil3d7pt")
         lr = importlib.import_module("repro.kernels.longrange3d")
         plane = blocking.padded_plane_bytes(n, n, 4)
+        lr_need = {8: 12.0, 128: 19.25, 1015: 28.996, 1024: 28.359}[n]
         assert s7.vmem_bytes(n, 4) >= 10.52 * plane
-        assert lr.vmem_bytes(n, 4) >= 28.99 * plane
+        assert lr.vmem_bytes(n, 4) >= lr_need * plane
 
 
 # ----------------------------------------------------------------------

@@ -78,6 +78,22 @@ def test_longrange3d_compiles(one_chip, n):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_longrange3d_reads_v_once(one_chip):
+    """At the paper's size the compiled kernel takes V as one operand (v,
+    u, roc, coeffs), not nine shifted copies of it: each V plane crosses
+    HBM once, into the kernel's VMEM ring."""
+    a = _spec((M, N, N), jnp.float32, one_chip)
+    c = _spec((5,), jnp.float32, one_chip)
+    text = _compile(
+        lambda u, v, roc, cf: ops.longrange3d(u, v, roc, cf,
+                                              interpret=False),
+        a, a, a, c).as_text()
+    calls = [re.search(r"custom-call\(([^)]*)\)", line)[1]
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert calls[0].count("%") == 4, calls[0]
+
+
 def test_stencil3d7pt_compiles(one_chip):
     a = _spec((M, N, N), jnp.float32, one_chip)
     c = _spec((7,), jnp.float32, one_chip)
@@ -98,10 +114,12 @@ def test_flash_attention_compiles(one_chip, b, sq, skv):
 
 
 def test_longrange3d_refused_beyond_vmem(one_chip):
-    """At N=1040 the wrapper's count (133.5 MiB) exceeds the chip's 128
+    """At N=1032 the wrapper's count (132.52 MiB) exceeds the chip's 128
     MiB: the wrapper refuses with its own error before compiling, and the
-    compiler, given the raw kernel, refuses too."""
-    n = 1040
+    compiler, given the raw kernel, refuses too (it was found to need
+    129.91 MiB there). From N=1025 on the count is above 128 MiB; the
+    compiler still fits N=1025-1028 and refuses N=1029-1032."""
+    n = 1032
     a = _spec((M, n, n), jnp.float32, one_chip)
     c = _spec((5,), jnp.float32, one_chip)
     with pytest.raises(ValueError, match="more than the chip's 128 MiB"):
@@ -114,11 +132,11 @@ def test_longrange3d_refused_beyond_vmem(one_chip):
 
 def test_vmem_count_admits_paper_sizes():
     """The counts the compiles above rely on, in numbers: the paper's
-    N=1015 fits both kernels, N=1040 does not fit the long-range one."""
+    N=1015 fits both kernels, N=1032 does not fit the long-range one."""
     vmem = 128 * 2**20
     assert _s7.vmem_bytes(N, 4) < vmem / 2
     assert _lr.vmem_bytes(N, 4) < _lr.vmem_bytes(1024, 4) < vmem
-    assert _lr.vmem_bytes(1040, 4) > vmem
+    assert _lr.vmem_bytes(1032, 4) > vmem
 
 
 # one instruction of compiled HLO text: name, result type(s), opcode
